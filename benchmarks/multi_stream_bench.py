@@ -120,5 +120,7 @@ def run(verbose: bool = True, tiny: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(tiny="--tiny" in sys.argv[1:])

@@ -185,5 +185,7 @@ def _run(sky, verbose, tiny):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     run(tiny="--tiny" in sys.argv[1:])

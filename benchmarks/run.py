@@ -284,7 +284,14 @@ def main() -> None:
         n_regressed = _compare(snap, compare_to)
         if n_regressed:
             sys.exit(1)
+    if errors:
+        # a module that raised recorded no metrics: its floors are gone
+        # from the snapshot, so the run fails rather than pass without them
+        sys.exit(f"run.py: {len(errors)} module(s) raised: "
+                 f"{sorted(errors)}")
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -3,19 +3,15 @@
 The partial/merge engine's point is horizontal scale: the same plan
 (Filter -> WindowAgg -> TopK) over the same rows, executed by a
 ``ShardedStore`` at 1/2/4/8 shards — each shard scans its own rows in
-parallel (its own XLA CPU device) and the merge combiner reduces the
+parallel (its own device) and the merge combiner reduces the
 fixed-shape partials. Reports per-shard-count scan throughput plus the
 ``sharded_query_bench`` summary row: the shard-count scaling curve and
 the 8-shard speedup over the 1-shard engine.
 
-Because shard devices only exist under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (which must be
-set before jax initializes), the benchmark re-executes itself in a
-subprocess with that flag and re-emits the subprocess's CSV rows —
-``benchmarks/run.py`` and ``scripts/tier1.sh --bench-smoke`` can call
-``run()`` from an already-initialized single-device process.
-
-    PYTHONPATH=src:. python benchmarks/sharded_warehouse_bench.py [--tiny]
+It runs in the calling process on the devices that process has, and
+fails when there are fewer devices than the largest shard count: on a
+CPU host give it ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+before jax initializes (``scripts/tier1.sh --bench-smoke`` does).
 
 ``--tiny`` is the seconds-scale smoke configuration (correctness +
 zero-recompile assertions, no speedup floor). The full run asserts the
@@ -25,18 +21,16 @@ agreement with the numpy reference.
 from __future__ import annotations
 
 import os
-import subprocess
 import sys
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_DEVFLAG = "--xla_force_host_platform_device_count=8"
 
 N_QUERIES = 16
 WINDOW = 500
 TOP_K = 10
 
 
-def _inner(tiny: bool) -> None:
+def run(verbose: bool = True, tiny: bool = False):
+    """Scan-throughput curve over 1..8 shards plus the fused Pallas
+    partial check; returns ``[speedup8]``."""
     import time
 
     import jax
@@ -46,7 +40,17 @@ def _inner(tiny: bool) -> None:
                                  execute_ref, windows_for)
     from repro.warehouse import query as Q
 
+    from benchmarks.common import emit
+
+    def row(name, us, derived):
+        if verbose:
+            emit(name, us, derived)
+
     counts = (1, 8) if tiny else (1, 2, 4, 8)
+    if jax.device_count() < counts[-1]:
+        raise RuntimeError(
+            f"sharded warehouse bench needs {counts[-1]} devices, found "
+            f"{jax.device_count()} ({jax.default_backend()})")
     T = 16_000 if tiny else 240_000
     n_streams = 64                      # divisible by every shard count
     rng = np.random.default_rng(7)
@@ -97,15 +101,14 @@ def _inner(tiny: bool) -> None:
                                    ref["quality"], rtol=1e-5, atol=1e-5)
         np.testing.assert_array_equal(np.asarray(mask), rmask)
         thr_mrows[S] = N_QUERIES * T / best / 1e6
-        print(f"warehouse_sharded/query/S{S}_T{T},"
-              f"{best / N_QUERIES * 1e6:.2f},"
-              f"scan={thr_mrows[S]:.1f}Mrows/s;shards={S};recompiles=0")
+        row(f"warehouse_sharded/query/S{S}_T{T}", best / N_QUERIES * 1e6,
+            f"scan={thr_mrows[S]:.1f}Mrows/s;shards={S};recompiles=0")
     speedup = thr_mrows[counts[-1]] / thr_mrows[1]
     cores = os.cpu_count() or 1
     curve = ";".join(f"s{S}={thr_mrows[S]:.1f}Mrows/s" for S in counts)
-    print(f"sharded_query_bench,{0.0:.2f},"
-          f"{curve};speedup8={speedup:.2f}x;host_cores={cores};"
-          f"rows={T};recompiles=0")
+    row("sharded_query_bench", 0.0,
+        f"{curve};speedup8={speedup:.2f}x;host_cores={cores};"
+        f"rows={T};recompiles=0")
     # the scan is compute-bound, so S shards can only beat 1 shard by
     # min(S, physical cores): enforce the 8-shard >=2x floor where the
     # host can physically run >=8 shard devices in parallel (an 8-core
@@ -148,49 +151,13 @@ def _inner(tiny: bool) -> None:
     n_scatter = census["totals"]["scatter_executed"]
     assert n_scatter == 0, \
         f"sharded Pallas partial executes {n_scatter} scatters"
-    print(f"warehouse_sharded/query_pallas/S{S}_T{T},0.00,"
-          f"scatter_ops=0;shards={S};exact=count;mean_rtol=1e-5")
-
-
-def run(verbose: bool = True, tiny: bool = False):
-    """Re-exec under a forced 8-device CPU topology and re-emit the
-    subprocess's CSV rows through benchmarks.common (so --json
-    snapshots include them)."""
-    from benchmarks.common import emit
-
-    env = dict(os.environ)
-    # appended last: XLA flag parsing is last-wins, so this overrides
-    # any device count the caller's environment already pinned
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _DEVFLAG).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(_ROOT, "src"), _ROOT,
-         env.get("PYTHONPATH", "")]).rstrip(os.pathsep)
-    cmd = [sys.executable, os.path.abspath(__file__), "--inner"]
-    if tiny:
-        cmd.append("--tiny")
-    p = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                       cwd=_ROOT)
-    if p.returncode != 0:
-        raise RuntimeError(
-            f"sharded bench subprocess failed:\n{p.stdout[-2000:]}\n"
-            f"{p.stderr[-2000:]}")
-    out = []
-    for line in p.stdout.splitlines():
-        parts = line.strip().split(",", 2)
-        if len(parts) == 3 and ("warehouse_sharded" in parts[0]
-                                or parts[0] == "sharded_query_bench"):
-            if verbose:
-                emit(parts[0], float(parts[1]), parts[2])
-            if "speedup8=" in parts[2]:
-                out.append(float(parts[2].split("speedup8=")[1]
-                                 .split("x")[0]))
-    return out
+    row(f"warehouse_sharded/query_pallas/S{S}_T{T}", 0.0,
+        f"scatter_ops=0;shards={S};exact=count;mean_rtol=1e-5")
+    return [speedup]
 
 
 if __name__ == "__main__":
-    if "--inner" in sys.argv[1:]:
-        _inner(tiny="--tiny" in sys.argv[1:])
-    else:
-        print("name,us_per_call,derived")
-        run(tiny="--tiny" in sys.argv[1:])
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    print("name,us_per_call,derived")
+    run(tiny="--tiny" in sys.argv[1:])

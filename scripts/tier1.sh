@@ -92,7 +92,12 @@ if [[ "$BENCH_SMOKE" == "1" ]]; then
   for bench in fused_ingest_bench warehouse_bench sharded_warehouse_bench \
                standing_query_bench multi_stream_bench pool_scale_bench; do
     echo "== bench smoke: ${bench} --tiny =="
-    PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    devflags="${XLA_FLAGS:-}"
+    if [[ "$bench" == "sharded_warehouse_bench" ]]; then
+      # runs on the devices it is given: 8 forced host devices here
+      devflags="${devflags:+$devflags }--xla_force_host_platform_device_count=8"
+    fi
+    XLA_FLAGS="$devflags" PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
       python "benchmarks/${bench}.py" --tiny
   done
   echo "== bench smoke: examples/vetl_observe.py (tiny traced run) =="

@@ -459,16 +459,12 @@ def _standing_args(kind: str, q: int = _Q_STAND, sharded: bool = False):
     standing-query group of ``q`` same-shape queries — the operand
     layout ``StandingQueries`` threads through the ingest kernels."""
     from repro.warehouse.query import normalize, split_plan
-    from repro.warehouse.standing import _num_groups
+    from repro.warehouse.standing import _seed_state
     spec, fv = normalize(_plan(kind))
     fvq = tuple(jnp.broadcast_to(a[None], (q,) + a.shape) for a in fv)
     _pre, node, _post = split_plan(spec)
-    num = _num_groups(node)
     lead = (N_SHARDS, q) if sharded else (q,)
-    fill = {"max": -jnp.inf, "min": jnp.inf}.get(node.agg, 0.0)
-    state = {"acc": jnp.full(lead + (num,), fill, jnp.float32),
-             "cnt": jnp.zeros(lead + (num,), jnp.float32)}
-    return spec, fvq, state
+    return spec, fvq, _seed_state(node, lead)
 
 
 def standing_backfill(kind: str, use_pallas: bool = False):

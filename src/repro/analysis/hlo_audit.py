@@ -14,7 +14,7 @@ checks and audits:
   through a ``conditional`` branch. Inside a ``shard_map`` body every
   shard must execute the identical collective sequence; a
   partition-id-predicated ``psum`` deadlocks the mesh (or silently
-  corrupts under ``check_rep=False``). The sharded property suite can
+  corrupts under ``check_vma=False``). The sharded property suite can
   only catch this probabilistically — the call graph catches it
   structurally.
 - **op accounting**: ``launch.hlo_analysis.analyze`` op counts plus its

@@ -32,6 +32,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Mapping, Tuple
 
 import jax
+import jax.extend.core as jex_core
 import numpy as np
 
 # primitive names that re-enter the host per execution
@@ -61,15 +62,15 @@ def _sub_jaxprs(params: Mapping[str, Any]):
     """Yield every sub-jaxpr in an equation's params (scan/while/cond
     bodies, pjit/shard_map inner jaxprs, custom_* call jaxprs)."""
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for b in v:
-                if isinstance(b, jax.core.ClosedJaxpr):
+                if isinstance(b, jex_core.ClosedJaxpr):
                     yield b.jaxpr
-                elif isinstance(b, jax.core.Jaxpr):
+                elif isinstance(b, jex_core.Jaxpr):
                     yield b
 
 

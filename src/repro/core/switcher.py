@@ -111,6 +111,14 @@ def init_state_multi(tables: List[SwitchTables]) -> Dict:
                         *[init_state(t) for t in tables])
 
 
+def _pick(x, *idx):
+    """``x[idx]`` for traced in-bounds indices (argmin/argmax results) as
+    an explicit fill-mode gather: a plain scalar index lowers to
+    ``dynamic_slice``, whose batching rule under ``vmap`` emits a CLIP
+    gather."""
+    return x.at[idx].get(mode="fill", fill_value=0)
+
+
 def _switch(state, qual_row, arrival, alpha, tables: SwitchTables):
     """One knob-switching decision (pure function of pytrees; vmappable
     over a leading stream axis on every argument)."""
@@ -121,8 +129,9 @@ def _switch(state, qual_row, arrival, alpha, tables: SwitchTables):
     col = jnp.take(tables.centers, state["k_cur"], axis=1)
     c = jnp.argmin(jnp.abs(col - state["qual_prev"]))
     # 2. usage-deficit pick (Eq. 6)
-    frac = state["used"][c] / jnp.maximum(state["count"][c], 1.0)
-    k_next = jnp.argmax(alpha[c] - frac)
+    frac = (_pick(state["used"], c)
+            / jnp.maximum(_pick(state["count"], c), 1.0))
+    k_next = jnp.argmax(_pick(alpha, c) - frac)
     # 3. placement feasibility
     rt_eff = tables.place_rt * arrival
     headroom = tau + (cap - state["buffer_s"])
@@ -133,20 +142,22 @@ def _switch(state, qual_row, arrival, alpha, tables: SwitchTables):
     feas_k = feas.any(axis=1)
     cl_masked = jnp.where(feas, tables.place_cl, jnp.inf)
     p_best = jnp.argmin(cl_masked, axis=1)                       # (K,)
-    eligible = tables.rank_pos >= tables.rank_pos[k_next]
+    eligible = tables.rank_pos >= _pick(tables.rank_pos, k_next)
     cand = feas_k & eligible
     pos1 = jnp.where(cand, tables.rank_pos, BIG)
     pos2 = jnp.where(feas_k, tables.rank_pos, BIG)
     k_sel = jnp.where(cand.any(), jnp.argmin(pos1), jnp.argmin(pos2))
-    p_sel = p_best[k_sel]
+    p_sel = _pick(p_best, k_sel)
     # overload shedding: if NO config/placement fits (arrival spike above
     # peak provisioning), drop the segment — Eq. 1 must hold universally
     # (the streaming-ETL load-shedding fallback; quality 0 for the drop)
     any_feas = feas_k.any()
-    rt = jnp.where(any_feas, rt_eff[k_sel, p_sel], 0.0)
-    on_s = jnp.where(any_feas, tables.place_on[k_sel, p_sel] * arrival, 0.0)
-    cl_s = jnp.where(any_feas, tables.place_cl[k_sel, p_sel] * arrival, 0.0)
-    qual = jnp.where(any_feas, qual_row[k_sel], 0.0)
+    rt = jnp.where(any_feas, _pick(rt_eff, k_sel, p_sel), 0.0)
+    on_s = jnp.where(any_feas,
+                     _pick(tables.place_on, k_sel, p_sel) * arrival, 0.0)
+    cl_s = jnp.where(any_feas,
+                     _pick(tables.place_cl, k_sel, p_sel) * arrival, 0.0)
+    qual = jnp.where(any_feas, _pick(qual_row, k_sel), 0.0)
     new_state = {
         "used": state["used"].at[c, k_sel].add(1.0),
         "count": state["count"].at[c].add(1.0),

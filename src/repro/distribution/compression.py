@@ -52,7 +52,7 @@ def compress_grads_across_pods(grads, err_tree, key, mesh):
     """shard_map wrapper: reduce gradient pytree across the 'pod' axis
     with int8 compression + error feedback. Grads must be identical in
     shape across pods (pure DP on the pod axis)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     leaves, treedef = jax.tree.flatten(grads)

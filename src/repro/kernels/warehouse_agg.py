@@ -159,9 +159,12 @@ def _agg_kernel(*refs, filters, keys, num, bn, wide, agg):
     cnt_ref[...] += jnp.sum(oh.astype(jnp.float32), axis=1)
     v = col_refs[-1][...].astype(jnp.float32)
     if agg in ("sum", "mean", "count"):
-        if wide:                                 # (bn, D) value column
+        if wide:                                 # (D, bn) value block
+            # HIGHEST: the MXU's default f32 pass rounds v to bf16-class
+            # precision (1e-4 relative on a v5e at 2^25 rows)
             acc_ref[...] += jax.lax.dot_general(
-                oh.astype(jnp.float32), v, (((1,), (0,)), ((), ())),
+                oh.astype(jnp.float32), v, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
         else:
             acc_ref[...] += jnp.sum(jnp.where(oh, v[None, :], 0.0), axis=1)
@@ -239,10 +242,15 @@ def fused_segment_agg(cols, n_rows, fvals, *, spec: FusedAggSpec,
            _vec(isint.astype(jnp.int32)), _vec(oob))
 
     col_specs = []
+    if wide:
+        # the (D, rows) view: a (rows, D) block pads D to 128 lanes on
+        # TPU (32 GiB for a 2^26-row, 9-wide column), while the store
+        # already lays the column out rows-minor, so the view is free
+        operands[-1] = operands[-1].T
     for arr in operands:
         if arr.ndim == 2:
-            col_specs.append(pl.BlockSpec((bn, arr.shape[1]),
-                                          lambda i: (i, 0)))
+            col_specs.append(pl.BlockSpec((arr.shape[0], bn),
+                                          lambda i: (0, i)))
         else:
             col_specs.append(pl.BlockSpec((bn,), lambda i: (i,)))
     acc_shape = (num, v.shape[1]) if wide else (num,)

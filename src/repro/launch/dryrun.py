@@ -77,7 +77,7 @@ def lower_cell(arch_name: str, shape_name: str, mesh, opts: RunOptions,
         compiled = lowered.compile()
         out["compile_s"] = round(time.time() - t0, 2)
 
-    ca = HA.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     out["cost_analysis"] = {"flops": ca.get("flops", 0.0),
                             "bytes": ca.get("bytes accessed", 0.0)}

@@ -67,15 +67,6 @@ def _call_operands(line: str, opcode: str):
             for t, n in _OPND_RE.findall(line[start:end])]
 
 
-def cost_analysis_dict(compiled) -> Dict:
-    """``compiled.cost_analysis()`` normalized across jax versions:
-    jax<0.5 returns a per-device list of dicts, newer returns one dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _shape_bytes(type_str: str) -> int:
     """Total bytes of a (possibly tuple) HLO type string."""
     total = 0

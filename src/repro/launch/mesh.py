@@ -6,21 +6,16 @@ import jax
 import numpy as np
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist from jax 0.5; on older
-    releases every axis is implicitly Auto, so simply omit the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_shard_mesh(n_shards: int):
@@ -34,7 +29,7 @@ def make_shard_mesh(n_shards: int):
     if n_shards > len(devs):
         return None
     if n_shards == len(devs):
-        return make_mesh_compat((n_shards,), ("shard",))
+        return auto_mesh((n_shards,), ("shard",))
     # a strict subset of the host's devices: build the Mesh directly
     # (jax.make_mesh insists on consuming every device)
     return jax.sharding.Mesh(np.asarray(devs[:n_shards]), ("shard",))
@@ -45,4 +40,4 @@ def make_host_mesh(model_axis: int = 1):
     n = len(jax.devices())
     model_axis = min(model_axis, n)
     data_axis = n // model_axis
-    return make_mesh_compat((data_axis, model_axis), ("data", "model"))
+    return auto_mesh((data_axis, model_axis), ("data", "model"))

@@ -21,7 +21,7 @@ from typing import Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -119,7 +119,7 @@ def _rebalance_kernel(mesh_new, s_old: int, s_new: int):
 
         return shard_map(body, mesh=mesh_new, in_specs=(P(), P()),
                          out_specs=(P("shard"), P("shard")),
-                         check_rep=False)(flat, owner)
+                         check_vma=False)(flat, owner)
 
     _REBALANCE_KERNELS[key] = kern
     return kern

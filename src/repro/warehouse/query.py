@@ -33,9 +33,10 @@ predicates are vmapped masks whose threshold VALUES are dynamic
 operands (re-querying with a new threshold, or after more rows arrive
 within the same chunk capacity, reuses the executable — assert it via
 ``compile_cache_size()`` / the registered ``warehouse_query`` probes).
-Aggregations use ``jax.ops.segment_sum`` with static group counts, so
-no data-dependent shapes ever materialize; filtered-out and padding
-rows participate as exact no-ops (weight 0 / -inf).
+Aggregations scatter into static group counts (float sums in two
+levels, ``_blocked_sum``), so no data-dependent shapes ever
+materialize; filtered-out and padding rows participate as exact no-ops
+(weight 0 / -inf).
 
 Aggregation partials have TWO interchangeable kernels behind
 ``use_pallas`` (see ``execute``): the XLA ``segment_sum`` path above,
@@ -52,9 +53,10 @@ runs the fused kernel per shard inside its single shard_map dispatch.
 validity mask over its rows (top-k slots beyond the number of matching
 groups are masked off). ``execute_ref`` is the plain-numpy reference
 implementation used by tests and the benchmark baseline; it replicates
-the kernel's row-order summation so fp32 results match exactly on a
-single shard (multi-shard float sums regroup the addition and match to
-tolerance; counts and integer-valued sums stay exact).
+the XLA path's blocked summation (``_blocked_sum``) so fp32 results
+match exactly on a single shard (multi-shard float sums regroup the
+addition and match to tolerance; counts and integer-valued sums stay
+exact).
 """
 from __future__ import annotations
 
@@ -66,7 +68,7 @@ from typing import Dict, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.registry import example_builder, register_engine
@@ -224,33 +226,80 @@ def _seg_ids(table, node):
     return jnp.clip(ids.astype(jnp.int32), 0, num - 1), num
 
 
+# rows per first-level block of every fp32 group sum (a power of two;
+# see _blocked_sum)
+SUM_BLOCK_ROWS = 1 << 14
+
+
+def _blocked_sum(closed, open_, v, ids, mask, rel, n_closed, nb):
+    """Two-level fp32 sum of one lane ``v``'s unmasked rows into their
+    groups ``ids`` — the addition order of every float group sum.
+
+    Rows fall in blocks of ``SUM_BLOCK_ROWS`` consecutive row positions
+    (``rel`` is each row's block, counted from the block the batch
+    starts in). Within a block each group adds its rows in row order
+    (one scatter into ``(nb, num)`` cells, block 0 seeded with
+    ``open_``, the partial of the block the previous batch left open);
+    the first ``n_closed`` block partials then add into ``closed`` in
+    block order and the next one is the new open partial. A row-order
+    sum over a million-row group drifts by percents once the running
+    sum's ulp nears the addends (3% at 8.4M rows on a v5e); here no
+    running sum spans more than one block's rows or one partial per
+    block. Masked rows route past the last cell and drop, so no masked
+    copy of ``v`` is made. Returns ``(closed, open)``."""
+    num = closed.shape[0]
+    cells = jnp.concatenate([open_, jnp.zeros(((nb - 1) * num,),
+                                              jnp.float32)])
+    cells = cells.at[jnp.where(mask, rel * num + ids, nb * num)].add(
+        v, mode="drop").reshape(nb, num)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (nb, num), 0)
+    grp = jax.lax.broadcasted_iota(jnp.int32, (nb, num), 1)
+    closed = closed.at[jnp.where(blk < n_closed, grp, num).reshape(-1)] \
+        .add(cells.reshape(-1), mode="drop")
+    # block n_closed's cells, picked by a sum with exact zeros
+    return closed, jnp.where(blk == n_closed, cells, 0.0).sum(axis=0)
+
+
+def _fold_sum(closed, open_, n, v, ids, mask, member=None):
+    """Fold a batch into a ``(closed, open)`` blocked sum whose first
+    ``n`` row positions are already in. ``member`` marks the batch rows
+    that take the next positions (in order; ``None``: every row);
+    ``mask`` the member rows that add. A ``(rows, D)`` value column
+    sums one lane at a time from its ``(D, rows)`` view (how the store
+    lays it out): a (rows, D) scatter pads D to 128 lanes on TPU,
+    32 GiB for a 2^26-row, 9-wide column."""
+    shift = SUM_BLOCK_ROWS.bit_length() - 1
+    rows = v.shape[0]
+    if member is None:
+        pos, count = n + jnp.arange(rows, dtype=jnp.int32), rows
+    else:
+        pos = n + jnp.cumsum(member.astype(jnp.int32)) - 1
+        count = member.sum(dtype=jnp.int32)
+    rel = (pos >> shift) - (n >> shift)
+    n_closed = ((n + count) >> shift) - (n >> shift)
+    nb = (rows >> shift) + 2
+    if v.ndim == 1:
+        return _blocked_sum(closed, open_, v, ids, mask, rel, n_closed, nb)
+    closed, open_ = jax.lax.map(
+        lambda a: _blocked_sum(a[0], a[1], a[2], ids, mask, rel, n_closed,
+                               nb), (closed.T, open_.T, v.T))
+    return closed.T, open_.T
+
+
 def _seg_partial(table, mask, node):
     """Masked segment accumulators — the per-shard PARTIAL of an agg
     node: {"acc", "cnt"}, fixed (num_groups,[D]) shapes, mergeable by
-    sum (sum/mean/count) or max/min. Filtered rows are exact no-ops."""
+    sum (sum/mean/count) or max/min. Filtered rows are exact no-ops.
+    Sums are ``_blocked_sum``s over the table's row positions."""
     ids, num = _seg_ids(table, node)
     v = table[node.value].astype(jnp.float32)
-    if node.agg in ("sum", "mean", "count"):
-        if v.ndim == 1:
-            # value and count share ONE scatter pass (the scatter is the
-            # whole cost of the kernel on CPU); per-column addition
-            # order is unchanged, so single-shard results still match
-            # the numpy reference bit-exact
-            both = jax.ops.segment_sum(
-                jnp.stack([jnp.where(mask, v, 0.0),
-                           mask.astype(jnp.float32)], axis=1),
-                ids, num_segments=num)
-            return {"acc": both[:, 0], "cnt": both[:, 1]}
-        # wide (row, D) value columns (the `out` embedding): plain
-        # masked segment_sum per lane
-        acc = jax.ops.segment_sum(jnp.where(mask[:, None], v, 0.0), ids,
-                                  num_segments=num)
-        cnt = jax.ops.segment_sum(mask.astype(jnp.float32), ids,
-                                  num_segments=num)
-        return {"acc": acc, "cnt": cnt}
-    assert v.ndim == 1, f"agg {node.agg!r} needs a scalar column"
     cnt = jax.ops.segment_sum(mask.astype(jnp.float32), ids,
                               num_segments=num)
+    if node.agg in ("sum", "mean", "count"):
+        zero = jnp.zeros((num,) + v.shape[1:], jnp.float32)
+        closed, open_ = _fold_sum(zero, zero, jnp.int32(0), v, ids, mask)
+        return {"acc": closed + open_, "cnt": cnt}
+    assert v.ndim == 1, f"agg {node.agg!r} needs a scalar column"
     if node.agg == "max":
         acc = jax.ops.segment_max(jnp.where(mask, v, -jnp.inf), ids,
                                   num_segments=num)
@@ -262,46 +311,39 @@ def _seg_partial(table, mask, node):
     return {"acc": acc, "cnt": cnt}
 
 
-def _seg_fold(part, table, mask, node):
-    """Fold a batch of NEW rows into a stored partial IN PLACE of the
-    zero/∓inf seed: the scatter that ``_seg_partial`` runs over a zeroed
-    accumulator runs here over the STORED accumulator instead. For
-    sum/mean/count this continues each group's fp32 addition sequence
-    exactly where the stored partial left off (the same row-order
-    scatter-accumulation contract ``_seg_partial``'s ``segment_sum``
-    already relies on for single-shard bit-exactness), so a backfill
-    followed by any number of ingest-time folds produces the BIT-EXACT
-    accumulator one ``_seg_partial`` over the concatenated rows would —
-    the standing-query engine's exactness contract (see
-    ``warehouse.standing``). max/min/count folds are order-independent
-    and exact regardless."""
+def _seg_fold(part, table, mask, node, member):
+    """Fold a batch of NEW rows into a standing partial ``{"acc",
+    "open", "cnt", "n"}``: ``n`` row positions are already in, ``acc``
+    holds the closed blocks' sum and ``open`` the partial of the block
+    position ``n`` falls in (``_fold_sum``). ``member`` marks the batch
+    rows that take the next positions, ``mask`` those that the plan's
+    filters keep. Each group's sum continues exactly where the stored
+    partial left off, so a backfill followed by any number of folds is
+    BIT-EXACT with one ``_seg_partial`` over the concatenated rows
+    (``acc + open`` there) — the standing-query engine's exactness
+    contract (see ``warehouse.standing``). max/min/count folds are
+    order-independent and exact regardless."""
     ids, num = _seg_ids(table, node)
     v = table[node.value].astype(jnp.float32)
+    out = dict(part,
+               cnt=part["cnt"].at[ids].add(mask.astype(jnp.float32),
+                                           mode="drop"),
+               n=part["n"] + member.sum(dtype=jnp.int32))
     if node.agg in ("sum", "mean", "count"):
-        if v.ndim == 1:
-            # same stacked value+count single-scatter layout as
-            # _seg_partial, seeded with the stored accumulators
-            both = jnp.stack([part["acc"], part["cnt"]], axis=1)
-            upd = jnp.stack([jnp.where(mask, v, 0.0),
-                             mask.astype(jnp.float32)], axis=1)
-            both = both.at[ids].add(upd, mode="drop")
-            return {"acc": both[:, 0], "cnt": both[:, 1]}
-        acc = part["acc"].at[ids].add(jnp.where(mask[:, None], v, 0.0),
-                                      mode="drop")
-        cnt = part["cnt"].at[ids].add(mask.astype(jnp.float32),
-                                      mode="drop")
-        return {"acc": acc, "cnt": cnt}
+        out["acc"], out["open"] = _fold_sum(part["acc"], part["open"],
+                                            part["n"], v, ids, mask,
+                                            member)
+        return out
     assert v.ndim == 1, f"agg {node.agg!r} needs a scalar column"
-    cnt = part["cnt"].at[ids].add(mask.astype(jnp.float32), mode="drop")
     if node.agg == "max":
-        acc = part["acc"].at[ids].max(jnp.where(mask, v, -jnp.inf),
-                                      mode="drop")
+        out["acc"] = part["acc"].at[ids].max(jnp.where(mask, v, -jnp.inf),
+                                             mode="drop")
     elif node.agg == "min":
-        acc = part["acc"].at[ids].min(jnp.where(mask, v, jnp.inf),
-                                      mode="drop")
+        out["acc"] = part["acc"].at[ids].min(jnp.where(mask, v, jnp.inf),
+                                             mode="drop")
     else:
         raise ValueError(f"unknown agg {node.agg!r}")
-    return {"acc": acc, "cnt": cnt}
+    return out
 
 
 def _seg_finalize(acc, cnt, agg):
@@ -536,6 +578,8 @@ class _CollectiveCombine:
         return jax.lax.pmin(x, self.axis)
 
     def concat(self, x):
+        if x.shape[0] == 0:       # an empty store: nothing to gather
+            return x              # (a zero-size all_gather fails to lower)
         return jax.lax.all_gather(x, self.axis, axis=0, tiled=True)
 
 
@@ -680,7 +724,7 @@ def _sharded_kernel(mesh, n_shards: int):
 
         return shard_map(body, mesh=mesh,
                          in_specs=(P("shard"), P("shard"), P(), P()),
-                         out_specs=P(), check_rep=False)(
+                         out_specs=P(), check_vma=False)(
                              cols, n_valid, fvals, key)
 
     _SHARDED_KERNELS[(mesh, n_shards)] = run
@@ -798,33 +842,39 @@ def _np_seg_ids(table, node):
     return np.clip(np.asarray(ids, np.int64), 0, num - 1), num
 
 
-def _np_aggregate(table, mask, node):
+def _np_aggregate(table, mask, node, dtype=np.float32):
     ids, num = _np_seg_ids(table, node)
-    v = np.asarray(table[node.value], np.float32)
+    v = np.asarray(table[node.value], dtype)
     agg = node.agg
-    cnt = np.zeros(num, np.float32)
-    np.add.at(cnt, ids[mask], np.float32(1.0))
+    cnt = np.zeros(num, dtype)
+    np.add.at(cnt, ids[mask], dtype(1.0))
     if agg == "count":
         out = cnt
     elif agg in ("sum", "mean"):
-        out = np.zeros((num,) + v.shape[1:], np.float32)
-        # np.add.at accumulates in row order — the same fp32 addition
-        # sequence as the kernel's segment_sum, so single-shard sums
-        # match bit-exact
-        np.add.at(out, ids[mask], v[mask])
+        # the XLA path's _blocked_sum, in the same order: each (block,
+        # group) cell adds its rows in row order, then each group adds
+        # its cells in block order (np.add.at is sequential), so
+        # single-shard fp32 sums match bit-exact
+        B, rows = SUM_BLOCK_ROWS, len(ids)
+        nb = rows // B + 2
+        cells = np.zeros((nb * num,) + v.shape[1:], dtype)
+        cell = (np.arange(rows) // B) * num + ids
+        np.add.at(cells, cell[mask], v[mask])
+        out = np.zeros((num,) + v.shape[1:], dtype)
+        np.add.at(out, np.tile(np.arange(num), nb), cells)
         if agg == "mean":
             c = np.maximum(cnt, 1.0)
             out = out / (c if out.ndim == 1 else c[:, None])
     elif agg == "max":
         assert v.ndim == 1, "max needs a scalar column"
-        out = np.full(num, -np.inf, np.float32)
+        out = np.full(num, -np.inf, dtype)
         np.maximum.at(out, ids[mask], v[mask])
-        out = np.where(cnt > 0, out, 0.0).astype(np.float32)
+        out = np.where(cnt > 0, out, 0.0).astype(dtype)
     elif agg == "min":
         assert v.ndim == 1, "min needs a scalar column"
-        out = np.full(num, np.inf, np.float32)
+        out = np.full(num, np.inf, dtype)
         np.minimum.at(out, ids[mask], v[mask])
-        out = np.where(cnt > 0, out, 0.0).astype(np.float32)
+        out = np.where(cnt > 0, out, 0.0).astype(dtype)
     else:
         raise ValueError(agg)
     return out, cnt
@@ -864,12 +914,18 @@ def _np_topk_idx(score, kk: int) -> np.ndarray:
     return np.argsort(~key, kind="stable")[:kk].astype(np.int32)
 
 
-def execute_ref(cols: Dict[str, np.ndarray], n_rows: int, plan):
+def execute_ref(cols: Dict[str, np.ndarray], n_rows: int, plan, *,
+                dtype=np.float32):
     """Plain-numpy mirror of ``execute`` (same clipping, masking, and
     summation-order semantics — including ``_seg_finalize``'s
     empty-group contract: 0.0 / count 0 / masked row for every agg,
     and ``lax.top_k``'s total-order tie-break). Returns ``(table,
-    mask)`` in numpy."""
+    mask)`` in numpy.
+
+    ``dtype`` is the accumulation dtype of the aggregates. ``float32``
+    (the default) mirrors the XLA path's addition sequence; ``float64``
+    gives the near-exact sums that every path is held to within a
+    count-dependent tolerance at million-row groups."""
     cap = len(next(iter(cols.values())))
     mask = np.arange(cap) < n_rows
     table = {k: np.asarray(v) for k, v in cols.items()}
@@ -887,7 +943,7 @@ def execute_ref(cols: Dict[str, np.ndarray], n_rows: int, plan):
         elif isinstance(node, Project):
             table = {c: table[c] for c in node.columns}
         elif isinstance(node, (GroupBy, WindowAgg, MultiGroupBy)):
-            out, cnt = _np_aggregate(table, mask, node)
+            out, cnt = _np_aggregate(table, mask, node, dtype)
             table, mask = _np_seg_table(node, out, cnt)
         elif isinstance(node, TopK):
             score = np.where(mask, table[node.by].astype(np.float32),

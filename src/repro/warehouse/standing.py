@@ -34,9 +34,10 @@ O(result) finalize — no rescan, ever.
   ``alerts_checked``, ``alerts_fired`` — see ``obs.telemetry``).
 
 Exactness contract (pinned by tests/test_standing_properties.py): the
-fold is ``query._seg_fold`` — the segment scatter SEEDED with the
-stored accumulator — so each group's fp32 addition sequence continues
-exactly where the previous fold stopped. A backfill plus any
+fold is ``query._seg_fold`` — the blocked segment sum SEEDED with the
+stored closed-block sum and open-block partial, at the row positions
+the state has counted — so each group's fp32 addition sequence
+continues exactly where the previous fold stopped. A backfill plus any
 interleaving of ingest folds is therefore bit-exact with one
 ``_seg_partial`` over all rows in ingest order: on the single-store
 path standing answers equal ``execute_ref`` bit-exactly (including
@@ -51,11 +52,12 @@ quantization error).
 
 The Pallas fused filter+group+aggregate kernel can compute the
 delta-partials (``use_pallas=True`` at registration, single-store path
-only — the sharded fold needs the ownership mask, which the fused
-kernel cannot express): zero-scatter folds with the same ``{"acc",
-"cnt"}`` convention. Its float sums accumulate tile-wise, so that path
-trades the bit-exact-sum contract for tolerance (max/min/count stay
-exact) — same trade the ``use_pallas`` query path documents.
+only; sharded stores fold on the XLA path): zero-scatter folds whose
+batch membership (every row of an append, the live slots of the
+elastic pool's masked tick) enters the kernel as one more filter
+column. Its float sums accumulate tile-wise, so that path trades the
+bit-exact-sum contract for tolerance (max/min/count stay exact) — same
+trade the ``use_pallas`` query path documents.
 """
 from __future__ import annotations
 
@@ -67,13 +69,13 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.registry import example_builder, register_engine
 from repro.core.switcher import register_cache_probe
 from repro.kernels.warehouse_agg import CMP as _CMP
-from repro.kernels.warehouse_agg import fused_segment_agg
+from repro.kernels.warehouse_agg import FusedAggSpec, fused_segment_agg
 from repro.warehouse.query import (Filter, GroupBy, MultiGroupBy, TopK,
                                    WindowAgg, _apply_nodes, _FilterRef,
                                    _pallas_spec, _resolve_use_pallas,
@@ -101,43 +103,74 @@ def _bucket(n: int) -> int:
 # the fold: new rows -> stored partials, traced inside the ingest kernels
 # ---------------------------------------------------------------------------
 
-def _fold_group(state, fvals, table, mask, n_new, *, spec, use_pallas):
+def _seed_state(node, lead, width=()):
+    """Fresh standing state of ``lead`` (``([S,] Q)``) query slots: the
+    closed-block sums ``acc`` (∓inf seeds for max/min), the open-block
+    partials ``open``, the counts, and ``n``, the row positions folded
+    in so far (``query._seg_fold``)."""
+    num = _num_groups(node)
+    fill = {"max": -jnp.inf, "min": jnp.inf}.get(node.agg, 0.0)
+    return {"acc": jnp.full(lead + (num,) + width, fill, jnp.float32),
+            "open": jnp.zeros(lead + (num,) + width, jnp.float32),
+            "cnt": jnp.zeros(lead + (num,), jnp.float32),
+            "n": jnp.zeros(lead, jnp.int32)}
+
+
+_MEMBER = "__member"          # the Pallas delta's batch-membership column
+
+
+def _fold_group(state, fvals, table, mask, *, spec, use_pallas):
     """Fold one plan-shape group's batch of new rows into its stacked
-    per-query state, vmapped over the leading query axis of ``(state,
-    fvals)``. ``table`` is the replicated new-rows column block,
-    ``mask`` the rows this shard owns (all rows on the single-store
-    path), ``n_new`` the valid prefix length (the Pallas delta path's
-    row bound — prefix-valid wherever that path is allowed)."""
+    per-query state, over the leading query axis of ``(state, fvals)``.
+    ``table`` is the replicated new-rows column block, ``mask`` the
+    rows that join this store, in order (every row of an append, the
+    live slots of a masked pool tick, the rows a shard owns)."""
     pre, node, _post = split_plan(spec)
 
     def one(st, fv):
         if not use_pallas:
             tbl, m = _apply_nodes(table, mask, fv, pre)
-            return _seg_fold(st, tbl, m, node)
-        # zero-scatter delta partial via the fused kernel, then an
-        # elementwise combiner fold (sum/max/min are the merge
-        # algebra of _merge_partials)
+            return _seg_fold(st, tbl, m, node, mask)
+        # zero-scatter delta partial via the fused kernel, the batch
+        # membership as one more filter (``member == 1``), then an
+        # elementwise combiner fold (sum/max/min are the merge algebra
+        # of _merge_partials)
         aspec = _pallas_spec(pre, node, table)
-        delta = fused_segment_agg(table, n_new, fv, spec=aspec)
+        aspec = FusedAggSpec(
+            filters=aspec.filters + ((_MEMBER, "eq", fv[0].shape[0]),),
+            keys=aspec.keys, value=aspec.value, agg=aspec.agg)
+        vals, floors, isint, oob = (
+            jnp.concatenate([a, jnp.full((1,), x, a.dtype)])
+            for a, x in zip(fv, (1.0, 1, True, 0)))
+        delta = fused_segment_agg(
+            dict(table, **{_MEMBER: mask.astype(jnp.int32)}),
+            jnp.int32(mask.shape[0]), (vals, floors, isint, oob),
+            spec=aspec)
         if node.agg == "max":
             acc = jnp.maximum(st["acc"], delta["acc"])
         elif node.agg == "min":
             acc = jnp.minimum(st["acc"], delta["acc"])
         else:
             acc = st["acc"] + delta["acc"]
-        return {"acc": acc, "cnt": st["cnt"] + delta["cnt"]}
+        return dict(st, acc=acc, cnt=st["cnt"] + delta["cnt"],
+                    n=st["n"] + mask.sum(dtype=jnp.int32))
 
-    return jax.vmap(one)(state, fvals)
+    if not use_pallas:
+        return jax.vmap(one)(state, fvals)
+    # one kernel launch per query slot: a batched pallas_call would
+    # block its (Q, F) threshold operands as (1, F) tiles, which the
+    # TPU lowering refuses (tiles must be (8, 128)-aligned)
+    return jax.lax.map(lambda a: one(*a), (state, fvals))
 
 
-def _fold_all(sstates, sfvals, table, mask, n_new, sspecs):
+def _fold_all(sstates, sfvals, table, mask, sspecs):
     """Every registered group's fold, in registration order — called
     INSIDE the store ingest kernels (see ``warehouse.store``), so the
     refresh shares their single dispatch. ``sspecs`` is the static
     tuple of ``(plan spec, use_pallas)`` pairs aligned with the
     ``sstates`` / ``sfvals`` operand tuples."""
     return tuple(
-        _fold_group(st, fv, table, mask, n_new, spec=sp, use_pallas=up)
+        _fold_group(st, fv, table, mask, spec=sp, use_pallas=up)
         for st, fv, (sp, up) in zip(sstates, sfvals, sspecs))
 
 
@@ -148,9 +181,8 @@ def _backfill(cols, n_rows, fvals, state, *, sspec):
     live prefix — after this, ingest folds keep the state current."""
     spec, use_pallas = sspec
     cap = next(iter(cols.values())).shape[0]
-    mask = jnp.arange(cap) < n_rows
-    return _fold_group(state, fvals, cols, mask, n_rows, spec=spec,
-                       use_pallas=use_pallas)
+    return _fold_group(state, fvals, cols, jnp.arange(cap) < n_rows,
+                       spec=spec, use_pallas=use_pallas)
 
 
 # (mesh, n_shards) -> jitted sharded backfill kernel; plain dict so the
@@ -169,7 +201,7 @@ def _sharded_fold_kernel(mesh, n_shards: int):
         if mesh is None:
             def one(c, n, st):
                 cap = next(iter(c.values())).shape[0]
-                return _fold_group(st, fvals, c, jnp.arange(cap) < n, n,
+                return _fold_group(st, fvals, c, jnp.arange(cap) < n,
                                    spec=spec, use_pallas=False)
             return jax.vmap(one)(cols, n_valid, state)
 
@@ -177,14 +209,14 @@ def _sharded_fold_kernel(mesh, n_shards: int):
             c0 = {k: v[0] for k, v in c.items()}
             cap = next(iter(c0.values())).shape[0]
             st2 = _fold_group(jax.tree.map(lambda x: x[0], st), fv, c0,
-                              jnp.arange(cap) < n[0], n[0], spec=spec,
+                              jnp.arange(cap) < n[0], spec=spec,
                               use_pallas=False)
             return jax.tree.map(lambda x: x[None], st2)
 
         return shard_map(body, mesh=mesh,
                          in_specs=(P("shard"), P("shard"), P(),
                                    P("shard")),
-                         out_specs=P("shard"), check_rep=False)(
+                         out_specs=P("shard"), check_vma=False)(
                              cols, n_valid, fvals, state)
 
     _SHARDED_FOLD[(mesh, n_shards)] = run
@@ -203,6 +235,8 @@ def _answer_kernel(state, fvals, *, spec, sharded):
 
     def one(st, fv):
         acc, cnt = st["acc"], st["cnt"]
+        if node.agg not in ("max", "min"):
+            acc = acc + st["open"]           # closed blocks + open block
         if sharded:
             if node.agg == "max":
                 acc = acc.max(axis=0)
@@ -284,14 +318,10 @@ class _Group:
     def _init_state(self, qb: Optional[int] = None):
         qb = self.qb if qb is None else qb
         reg, node = self.reg, self.node
-        num = _num_groups(node)
         vcol = reg.host.columns[node.value]
         width = vcol.shape[(2 if reg.sharded else 1):]   # () or (D,)
         lead = (reg.host.n_shards, qb) if reg.sharded else (qb,)
-        fill = {"max": -jnp.inf, "min": jnp.inf}.get(node.agg, 0.0)
-        return reg._place({
-            "acc": jnp.full(lead + (num,) + width, fill, jnp.float32),
-            "cnt": jnp.zeros(lead + (num,), jnp.float32)})
+        return reg._place(_seed_state(node, lead, width))
 
     def _restack_fvals(self) -> None:
         """(Qb, F) stacked dynamic threshold operands; padding slots
@@ -409,9 +439,7 @@ class StandingQueries:
             raise ValueError(f"plan references unknown columns {missing}")
 
     def _resolve_pallas(self, flag, spec) -> bool:
-        if self.sharded:
-            # the sharded fold masks rows by ownership, which the fused
-            # kernel's prefix-validity bound cannot express
+        if self.sharded:                 # sharded stores fold on XLA
             return False
         pre, node, _post = split_plan(spec)
         cols = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)

@@ -53,7 +53,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.registry import example_builder, register_engine
@@ -147,8 +147,7 @@ def _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs):
     # stored column dtypes (the standing exactness contract)
     cast = {k: v.astype(cols[k].dtype) for k, v in upd.items()}
     n = upd["t"].shape[0]
-    states = _fold_all(sstates, sfvals, cast, jnp.ones((n,), bool),
-                       jnp.int32(n), sspecs)
+    states = _fold_all(sstates, sfvals, cast, jnp.ones((n,), bool), sspecs)
     return new, states
 
 
@@ -228,7 +227,7 @@ def _ingest_tick_masked(cols, traces, quality, out_vecs, t, offset,
     if not sspecs:
         return new
     cast = {k: v.astype(cols[k].dtype) for k, v in upd.items()}
-    states = _fold_all(sstates, sfvals, cast, keep, jnp.int32(V), sspecs)
+    states = _fold_all(sstates, sfvals, cast, keep, sspecs)
     return new, states
 
 
@@ -259,12 +258,11 @@ class SegmentStore:
         if need <= self.capacity:
             return
         cap = _bucket_cap(need, self.chunk_rows)
-        grown = _empty_columns(cap, self.out_dim)
-        if self.n_rows:
-            grown = {k: jax.lax.dynamic_update_slice(
-                grown[k], self.columns[k], (0,) * grown[k].ndim)
-                for k in grown}
-        self.columns = grown
+        # pad in place of zeros + update: a grown 2^26-row store is
+        # 6 GiB on a v5e, and old + zeros + grown would not fit in HBM
+        self.columns = {
+            k: jnp.pad(v, ((0, cap - v.shape[0]),) + ((0, 0),) * (v.ndim - 1))
+            for k, v in self.columns.items()}
 
     # -- ingestion -----------------------------------------------------
     def ingest_fused(self, traces, out_vecs, *, stream_id: int = 0,
@@ -526,7 +524,6 @@ def _append_traced(cols, n_rows, upd, mesh, n_shards, sstates=(),
     if valid is not None:
         owner = jnp.where(jnp.asarray(valid, bool), owner,
                           jnp.int32(n_shards))
-    n = owner.shape[0]
     if mesh is None:
         sids = jnp.arange(n_shards, dtype=jnp.int32)
         if not sspecs:
@@ -536,8 +533,7 @@ def _append_traced(cols, n_rows, upd, mesh, n_shards, sstates=(),
         def one(c, nr, s, sts):
             new, nn = _route_write(c, nr, upd, owner, s)
             cast = {k: upd[k].astype(c[k].dtype) for k in upd}
-            states = _fold_all(sts, sfvals, cast, owner == s,
-                               jnp.int32(n), sspecs)
+            states = _fold_all(sts, sfvals, cast, owner == s, sspecs)
             return new, nn, states
 
         return jax.vmap(one)(cols, n_rows, sids, sstates)
@@ -551,7 +547,7 @@ def _append_traced(cols, n_rows, upd, mesh, n_shards, sstates=(),
             return stacked, n2[None]
         cast = {k: u[k].astype(c0[k].dtype) for k in u}
         states = _fold_all(jax.tree.map(lambda x: x[0], sts), fvs,
-                           cast, ow == sid, jnp.int32(n), sspecs)
+                           cast, ow == sid, sspecs)
         return stacked, n2[None], jax.tree.map(lambda x: x[None], states)
 
     out_specs = (P("shard"), P("shard")) if not sspecs \
@@ -560,7 +556,7 @@ def _append_traced(cols, n_rows, upd, mesh, n_shards, sstates=(),
                      in_specs=(P("shard"), P("shard"), P(), P(),
                                P("shard"), P()),
                      out_specs=out_specs,
-                     check_rep=False)(cols, n_rows, upd, owner, sstates,
+                     check_vma=False)(cols, n_rows, upd, owner, sstates,
                                       sfvals)
 
 

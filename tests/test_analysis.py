@@ -12,7 +12,7 @@ import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import registry
@@ -44,7 +44,7 @@ def test_callback_under_scan_flagged():
 
 
 def test_f64_leak_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fn = jax.jit(lambda x: x.astype(jnp.float64) * 2)
         checks, _ = _jaxpr_checks(fn, (jnp.ones(3, jnp.float32),))
     assert "f64" in checks
@@ -108,7 +108,7 @@ def test_unbalanced_collective_flagged():
                             lambda v: v * 2.0, x)
 
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("shard"),
-                           out_specs=P("shard"), check_rep=False))
+                           out_specs=P("shard"), check_vma=False))
     hlo = fn.lower(jnp.ones((4, 2))).compile().as_text()
     v, _ = audit_hlo(hlo, INV)
     assert "unbalanced_collective" in [x["check"] for x in v]
@@ -122,7 +122,7 @@ def test_balanced_collective_clean():
         return jax.lax.psum(x, "shard")   # unconditional: every shard
 
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("shard"),
-                           out_specs=P(), check_rep=False))
+                           out_specs=P(), check_vma=False))
     hlo = fn.lower(jnp.ones((4, 2))).compile().as_text()
     v, info = audit_hlo(hlo, INV)
     assert v == []

@@ -54,12 +54,12 @@ def test_compressed_psum_error_feedback_unbiased_over_steps():
     mesh): carrying its error residual across steps makes the
     accumulated compressed reduction converge to the true accumulated
     mean — compression noise stays unbiased over steps."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import auto_mesh
 
-    mesh = make_mesh_compat((1,), ("pod",))
+    mesh = auto_mesh((1,), ("pod",))
     spec = P()
     # build + jit the shard_map ONCE (key is a traced operand) so the
     # 200-step loop reuses a single executable

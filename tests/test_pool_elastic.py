@@ -215,6 +215,24 @@ def test_shed_surfaces_as_standing_alerts(sky):
     assert fired[2]                        # lowest priority shed -> alert
 
 
+def test_pallas_standing_fold_skips_inactive_slots(sky):
+    """The masked tick lands rows for active slots only; a standing
+    query whose fold runs the fused kernel must count only those rows
+    (empty slots carry stream id 0 on the slot axis)."""
+    sink = SegmentStore(out_dim=len(sky.configs), chunk_rows=32)
+    reg = StandingQueries(sink)
+    h = reg.register([Filter("stream_id", "eq", 0.0),
+                      GroupBy("k", "quality", agg="count",
+                              num_groups=len(sky.configs))],
+                     use_pallas=True)
+    pool = SkyscraperPool(sky, n_streams=3, sink=sink, slot_chunk=8)
+    assert pool.cap == 8
+    for _ in range(4):
+        pool.process([np.zeros(3)] * pool.V)
+    table, _ = reg.answer(h)
+    assert float(np.asarray(table["count"]).sum()) == 4.0
+
+
 def test_admission_control_refuses_infeasible(sky):
     cost_min = float(np.min(np.asarray(sky.tables.cost)))
     pool = SkyscraperPool(sky, n_streams=2,

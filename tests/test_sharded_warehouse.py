@@ -276,6 +276,11 @@ def test_empty_shards_and_empty_result():
             TopK(3, by="quality"))
     _, m = store.query(dead)
     assert not np.asarray(m).any()
+    # a store with no rows yet still answers a row-level top-k (its
+    # zero-size partials skip the cross-shard gather)
+    empty = ShardedStore(out_dim=2, n_shards=1, chunk_rows=64)
+    _, m = empty.query((TopK(3, by="quality"),))
+    assert np.asarray(m).shape == (0,)
 
 
 def test_sharded_zero_recompiles():

@@ -29,8 +29,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from jax.sharding import PartitionSpec as P
 from repro.distribution.sharding import spec_for
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model_axis=4)
 # divisible -> sharded
 s1 = spec_for((16, 8), ("fsdp", "tensor"), mesh)
 assert s1 == P("data", "model"), s1
@@ -58,8 +58,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch import hlo_analysis as HA
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model_axis=4)
 L, d, ff = 6, 128, 256
 params = {"w1": jax.ShapeDtypeStruct((L, d, ff), jnp.float32),
           "w2": jax.ShapeDtypeStruct((L, ff, d), jnp.float32)}
@@ -77,7 +77,7 @@ def run(unroll):
         h, _ = jax.lax.scan(body, x, p, unroll=L if unroll else 1)
         return h.mean()
     co = jax.jit(step, in_shardings=(ps, xs)).lower(params, x).compile()
-    flops_ca = HA.cost_analysis_dict(co).get("flops", 0.0)
+    flops_ca = (co.cost_analysis() or {}).get("flops", 0.0)
     parsed = HA.analyze(co.as_text())
     return flops_ca, parsed["dot_flops"]
 

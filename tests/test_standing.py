@@ -8,6 +8,7 @@ the bucketed capacity ladder).
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the sharded
 legs execute on a real mesh."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -16,6 +17,7 @@ from repro.warehouse import (Filter, GroupBy, MultiGroupBy, SegmentStore,
                              ShardedStore, ShardedTieredStore,
                              StandingQueries, TieredStore, TopK,
                              WindowAgg, execute_ref)
+from repro.warehouse.query import SUM_BLOCK_ROWS
 from repro.warehouse.store import _bucket_cap
 from test_warehouse import _host_cols, _random_rows
 
@@ -294,6 +296,82 @@ def test_pallas_delta_fold_matches_ref():
     _eq(mask, rmask)
     _eq(table["quality"], ref["quality"])
     _eq(table["count"], ref["count"])
+
+
+def _tick(store, rng, V, t, valid):
+    """One elastic-pool tick of random rows; ``valid`` marks live slots."""
+    traces = {"c": rng.integers(0, 4, V).astype(np.int32),
+              "k": rng.integers(0, D, V).astype(np.int32),
+              "qual": rng.random(V).astype(np.float32),
+              "on_s": (rng.random(V) * 20).astype(np.float32),
+              "cl_s": (rng.random(V) * 5).astype(np.float32),
+              "buffer_s": (rng.random(V) * 40).astype(np.float32)}
+    store.ingest_tick({k: jnp.asarray(v) for k, v in traces.items()},
+                      quality=traces["qual"],
+                      out_vecs=rng.random((V, D)).astype(np.float32),
+                      t=t, stream_ids=np.arange(V), valid=valid)
+
+
+def test_pallas_delta_fold_masked_tick_matches_ref():
+    """The Pallas delta folds the elastic pool's masked ticks as well:
+    the live-slot mask enters the fused kernel as one more filter, so
+    retired slots fold nothing; max/count stay exact, float sums match
+    to tolerance."""
+    store = SegmentStore(out_dim=D, chunk_rows=256)
+    store.append_rows(_random_rows(300, D, seed=21))
+    reg = StandingQueries(store)
+    plans = [(Filter("k", "gt", 0.5),
+              GroupBy("category", "quality", agg="max", num_groups=4)),
+             (GroupBy("category", "on_core_s", agg="sum", num_groups=4),)]
+    hs = [reg.register(p, use_pallas=True) for p in plans]
+    assert all(reg._group_of(reg._queries[h]).use_pallas for h in hs)
+    rng = np.random.default_rng(22)
+    for tick in range(3):
+        _tick(store, rng, 64, 300 + tick, rng.random(64) < 0.6)
+    for h, plan in zip(hs, plans):
+        table, mask = reg.answer(h)
+        ref, rmask = _ref(store, plan)
+        _eq(mask, rmask)
+        _eq(table["count"], ref["count"])
+        _eq(table["category"], ref["category"])
+        value = plan[-1].value
+        if plan[-1].agg == "max":
+            _eq(table[value], ref[value])
+        else:
+            _close(table[value], ref[value], rtol=1e-5)
+
+
+def test_standing_folds_bit_exact_across_sum_blocks():
+    """Appends and masked ticks that start, span and end inside the
+    ``SUM_BLOCK_ROWS`` blocks of the blocked float sums stay bit-exact
+    with the rescan: the state carries the open block's partial and
+    the row positions folded so far (inactive slots take none)."""
+    B = SUM_BLOCK_ROWS
+    store = SegmentStore(out_dim=D, chunk_rows=4096)
+    store.append_rows(_random_rows(B - 5, D, seed=40))
+    reg = StandingQueries(store)
+    plans = [
+        (Filter("quality", "ge", 0.25),
+         GroupBy("category", "quality", agg="sum", num_groups=4)),
+        (WindowAgg(window=8192, value="on_core_s", agg="mean",
+                   num_windows=10),),
+        (GroupBy("k", "out", agg="sum", num_groups=D),),
+    ]
+    handles = [reg.register(p, use_pallas=False) for p in plans]
+    t0 = B - 5
+    for i, n in enumerate((3, 2 * B + 11, B)):
+        store.append_rows(_random_rows(n, D, seed=41 + i, t0=t0))
+        t0 += n
+    rng = np.random.default_rng(45)
+    for tick in range(3):
+        _tick(store, rng, 4096, t0 + tick, rng.random(4096) < 0.7)
+    assert store.n_rows > 4 * B
+    for h, plan in zip(handles, plans):
+        table, mask = reg.answer(h)
+        ref, rmask = _ref(store, plan)
+        _eq(mask, rmask)
+        for k in ref:
+            _eq(table[k], ref[k], err_msg=f"{plan}:{k}")
 
 
 def test_pallas_flag_ignored_on_sharded():
