@@ -9,31 +9,35 @@
    costs zero extra dispatches.
 2. Land the run in a SegmentStore sink and read the store-side
    counters: rows per shard, ingest-to-queryable lag, dispatch counts.
-3. Trace the fused engines with the dispatch tracer (``repro.obs``):
-   wall-time spans, executable/recompile deltas, a Chrome-trace JSON
-   you can drop into chrome://tracing or Perfetto.
+3. Trace a few ticks of a serving pool with ``jax.profiler``: the
+   program's spans (``pool.tick``, ``pool.transform``, ``sink.ingest``,
+   ``host.gc``, ... — the table in ``repro.obs``) land in the same
+   trace as the device's ops, with their counts as stats. Open the
+   directory in TensorBoard's profile plugin, or its
+   ``perfetto_trace.json.gz`` in Perfetto.
 
-The full tracer run over EVERY engine plus the regression gate against
+The dispatch audit over EVERY engine plus the regression gate against
 the committed baseline is one command::
 
     python -m repro.obs --json OBS_NEW.json --compare OBS.json
 """
-import json
+import glob
 import os
 import sys
 import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax
 import numpy as np
 
 from repro.configs.workloads import COVID
 from repro.core import ingest as IG
+from repro.core.api import Skyscraper, SkyscraperPool
 from repro.core.offline import fit
 from repro.data.stream import generate
-from repro.obs import validate_chrome_trace
-from repro.obs.trace import trace_all
-from repro.warehouse import Filter, SegmentStore, TopK
+from repro.warehouse import (Filter, GroupBy, SegmentStore,
+                             StandingQueries, TopK)
 
 
 def main():
@@ -69,25 +73,45 @@ def main():
     # fused batch ingest: row t waited T-1-t ticks before queryable
     assert stel.lag_max_ticks == stream.n_segments - 1
 
-    print("\n== 3. dispatch tracer over the fused engines ==")
-    records, trace = trace_all(only="fused", reps=2)
-    for name, r in sorted(records.items()):
-        if "skipped" in r:
-            print(f"   {name:28s} SKIP ({r['skipped']})")
-            continue
-        print(f"   {name:28s} span={r['span_us']:9.1f}us "
-              f"exec+{r['new_executables']} "
-              f"recompile={r['recompiles']}")
-        assert r["recompiles"] == 0
-    problems = validate_chrome_trace(trace)
-    assert not problems, problems
-    out = os.path.join(tempfile.gettempdir(), "vetl_trace.json")
-    with open(out, "w") as f:
-        json.dump(trace, f)
-    print(f"   wrote {len(trace['traceEvents'])} spans to {out}")
-    print("   (open in chrome://tracing; gate a CI run with "
-          "`python -m repro.obs --compare OBS.json`)")
-    print("\nOK: flight recorder + dispatch tracer both healthy.")
+    print("\n== 3. a traced serving pool: the program's spans ==")
+    sky = Skyscraper(segment_seconds=2.0, n_categories=3)
+    sky.set_resources(num_cores=4)
+    sky.register_knob("det", [1, 5, 10])
+
+    def proc(seg, kv):
+        return seg, float(np.clip(1 - seg * (1 - 1.0 / kv["det"]), 0, 1))
+
+    sky.fit(list(np.linspace(0, 1, 40)), proc, plan_segments=4)
+    V = 8
+    sink = SegmentStore(out_dim=len(sky.configs), chunk_rows=64)
+    StandingQueries(sink).subscribe(
+        (GroupBy("stream_id", "buffer_s", "max", num_groups=V),),
+        Filter("buffer_s", "gt", 30.0))
+    pool = SkyscraperPool(sky, n_streams=V, sink=sink, telemetry=True)
+    rng = np.random.default_rng(0)
+    pool.process(list(rng.random(V)))        # compile outside the trace
+    logdir = tempfile.mkdtemp(prefix="vetl_profile_")
+    with jax.profiler.trace(logdir, create_perfetto_trace=True):
+        for _ in range(8):
+            pool.process(list(rng.random(V)))
+    path, = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                      recursive=True)
+    counts = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("pool.", "sink.", "host.")):
+                    counts[e.name] = counts.get(e.name, 0) + 1
+    for name, n in sorted(counts.items()):
+        print(f"   {name:18s} x{n}")
+    assert counts["pool.tick"] == 8 and counts["sink.alert_poll"] == 8
+    ex = pool.telemetry().extras
+    print(f"   transfers: host_pulls={ex['host_pulls']:.0f} "
+          f"uploads={ex['uploads']:.0f} (pool), "
+          f"{sink.obs['host_pulls']}/{sink.obs['uploads']} (sink)")
+    print(f"   wrote the profile to {logdir} (TensorBoard; Perfetto: "
+          f"perfetto_trace.json.gz)")
+    print("\nOK: flight recorder, counters and program spans healthy.")
 
 
 if __name__ == "__main__":

@@ -6,7 +6,7 @@
 # Legs:
 #   0. doc drift: scripts/check_docs.py (README + docs/ paths and flags);
 #   1. the full suite on the default (single-device) topology;
-#   2. static program audit + obs dispatch-trace smoke vs the committed
+#   2. static program audit + obs dispatch-audit smoke vs the committed
 #      ANALYSIS.json / OBS.json baselines;
 #   3. the sharded-warehouse suite re-run under a forced 8-device host
 #      platform, where ShardedStore gets a real ('shard',) mesh and
@@ -51,15 +51,13 @@ AUDIT_OUT="$(mktemp)"
 python -m repro.analysis --json "$AUDIT_OUT" --compare ANALYSIS.json
 rm -f "$AUDIT_OUT"
 
-echo "== obs dispatch-trace smoke vs OBS.json =="
-# trace every registry engine (1 warm rep), validate the Chrome trace,
-# and gate vs the committed baseline: any new executable / recompile /
-# host transfer fails; span-time floors only gate above the noise floor
+echo "== obs dispatch-audit smoke vs OBS.json =="
+# run every registry engine cold and warm (1 warm rep) and gate vs the
+# committed baseline: any new executable / recompile / host transfer
+# fails
 OBS_OUT="$(mktemp)"
-OBS_TRACE="$(mktemp)"
-python -m repro.obs --smoke --json "$OBS_OUT" --trace "$OBS_TRACE" \
-  --compare OBS.json
-rm -f "$OBS_OUT" "$OBS_TRACE"
+python -m repro.obs --smoke --json "$OBS_OUT" --compare OBS.json
+rm -f "$OBS_OUT"
 
 echo "== sharded warehouse suite on 8 forced host devices =="
 # appended last: XLA flag parsing is last-wins, so this overrides any
@@ -78,15 +76,13 @@ XLA_FLAGS="${XLA_FLAGS:+$XLA_FLAGS }--xla_force_host_platform_device_count=8" \
   python -m repro.analysis --json "$AUDIT_OUT"
 rm -f "$AUDIT_OUT"
 
-echo "== obs dispatch-trace smoke on 8 forced host devices =="
+echo "== obs dispatch-audit smoke on 8 forced host devices =="
 # --compare on a different topology skips per-engine gates but still
-# proves the tracer runs (and the trace validates) with real collectives
+# proves the audit runs with real collectives
 OBS_OUT="$(mktemp)"
-OBS_TRACE="$(mktemp)"
 XLA_FLAGS="${XLA_FLAGS:+$XLA_FLAGS }--xla_force_host_platform_device_count=8" \
-  python -m repro.obs --smoke --json "$OBS_OUT" --trace "$OBS_TRACE" \
-    --compare OBS.json
-rm -f "$OBS_OUT" "$OBS_TRACE"
+  python -m repro.obs --smoke --json "$OBS_OUT" --compare OBS.json
+rm -f "$OBS_OUT"
 
 if [[ "$BENCH_SMOKE" == "1" ]]; then
   for bench in fused_ingest_bench warehouse_bench sharded_warehouse_bench \

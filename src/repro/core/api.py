@@ -33,6 +33,8 @@ from repro.core.switcher import (SwitchTables, _masked_switch, init_state,
                                  init_state_multi, register_cache_probe,
                                  stack_tables, switch_step,
                                  switch_step_multi)
+from repro.obs.spans import install_gc_spans, span
+from repro.obs.telemetry import HostTelemetry
 
 
 class Skyscraper:
@@ -189,12 +191,14 @@ def _pool_replan(params, bufs, centers, cost, budget, use_model, *,
     back to the uniform prior until the buffers have filled once —
     flipping it never recompiles."""
     C = centers.shape[0]
-    r_model = jax.vmap(lambda b: forecast_from_labels(
-        params, b, C, n_split=n_split, interval=interval))(bufs)
-    r = jnp.where(use_model, r_model,
-                  jnp.full_like(r_model, 1.0 / C))
-    return jax.vmap(lambda rv: solve_lp_lagrangian(centers, cost, rv,
-                                                   budget))(r)
+    with jax.named_scope("pool.forecast"):
+        r_model = jax.vmap(lambda b: forecast_from_labels(
+            params, b, C, n_split=n_split, interval=interval))(bufs)
+        r = jnp.where(use_model, r_model,
+                      jnp.full_like(r_model, 1.0 / C))
+    with jax.named_scope("pool.lp"):
+        return jax.vmap(lambda rv: solve_lp_lagrangian(centers, cost, rv,
+                                                       budget))(r)
 
 
 _pool_shift = jax.jit(lambda bufs, c: jnp.concatenate(
@@ -214,14 +218,16 @@ def _pool_replan_stacked(params, bufs, centers, cost, budget, use_model,
     so they contribute nothing to the joint spend; flipping ``active``
     / ``priority`` / ``budget`` values never recompiles."""
     C = centers.shape[0]
-    r_model = jax.vmap(lambda b: forecast_from_labels(
-        params, b, C, n_split=n_split, interval=interval))(bufs)
-    r = jnp.where(use_model, r_model,
-                  jnp.full_like(r_model, 1.0 / C))
-    r = r * jnp.asarray(active, jnp.float32)[:, None]
+    with jax.named_scope("pool.forecast"):
+        r_model = jax.vmap(lambda b: forecast_from_labels(
+            params, b, C, n_split=n_split, interval=interval))(bufs)
+        r = jnp.where(use_model, r_model,
+                      jnp.full_like(r_model, 1.0 / C))
+        r = r * jnp.asarray(active, jnp.float32)[:, None]
     V = bufs.shape[0]
     qual = jnp.broadcast_to(centers, (V,) + centers.shape)
-    return solve_lp_stacked(qual, cost, r, budget, weights=priority)
+    with jax.named_scope("pool.lp"):
+        return solve_lp_stacked(qual, cost, r, budget, weights=priority)
 
 
 def _pool_tick_fn(state, q_meas, q_valid, quals, arr, active, priority,
@@ -247,32 +253,35 @@ def _pool_tick_fn(state, q_meas, q_valid, quals, arr, active, priority,
     state = dict(state, qual_prev=jnp.where(jnp.asarray(q_valid, bool),
                                             q_meas, state["qual_prev"]))
     pre_buf = state["buffer_s"]
-    new_state, outs = jax.vmap(_masked_switch)(
-        state, quals, arr, active, alpha, tables)
-    demand = outs["on_s"]
-    order = jnp.argsort(jnp.where(active, -priority, jnp.inf))
-    keep = jnp.zeros_like(active).at[order].set(
-        jnp.cumsum(demand[order]) <= capacity_core_s)
-    hwm_s = watermark_frac * jnp.asarray(tables.buffer_cap_s, jnp.float32)
-    shed = active & ~outs["dropped"] & (~keep | (pre_buf >= hwm_s))
-    tau = jnp.asarray(tables.tau, jnp.float32)
-    shed_buf = jnp.maximum(pre_buf - tau, 0.0)
-    new_state = dict(
-        new_state,
-        buffer_s=jnp.where(shed, shed_buf, new_state["buffer_s"]),
-        cloud_spent=jnp.where(shed,
-                              new_state["cloud_spent"] - outs["cl_s"],
-                              new_state["cloud_spent"]),
-        qual_prev=jnp.where(shed, 0.0, new_state["qual_prev"]))
-    zero = jnp.float32(0.0)
-    outs = dict(outs,
-                qual=jnp.where(shed, zero, outs["qual"]),
-                on_s=jnp.where(shed, zero, outs["on_s"]),
-                cl_s=jnp.where(shed, zero, outs["cl_s"]),
-                rt=jnp.where(shed, zero, outs["rt"]),
-                buffer_s=jnp.where(shed, shed_buf, outs["buffer_s"]),
-                dropped=outs["dropped"] | shed,
-                shed=shed)
+    with jax.named_scope("pool.switch"):
+        new_state, outs = jax.vmap(_masked_switch)(
+            state, quals, arr, active, alpha, tables)
+    with jax.named_scope("pool.shed"):
+        demand = outs["on_s"]
+        order = jnp.argsort(jnp.where(active, -priority, jnp.inf))
+        keep = jnp.zeros_like(active).at[order].set(
+            jnp.cumsum(demand[order]) <= capacity_core_s)
+        hwm_s = watermark_frac * jnp.asarray(tables.buffer_cap_s,
+                                             jnp.float32)
+        shed = active & ~outs["dropped"] & (~keep | (pre_buf >= hwm_s))
+        tau = jnp.asarray(tables.tau, jnp.float32)
+        shed_buf = jnp.maximum(pre_buf - tau, 0.0)
+        new_state = dict(
+            new_state,
+            buffer_s=jnp.where(shed, shed_buf, new_state["buffer_s"]),
+            cloud_spent=jnp.where(shed,
+                                  new_state["cloud_spent"] - outs["cl_s"],
+                                  new_state["cloud_spent"]),
+            qual_prev=jnp.where(shed, 0.0, new_state["qual_prev"]))
+        zero = jnp.float32(0.0)
+        outs = dict(outs,
+                    qual=jnp.where(shed, zero, outs["qual"]),
+                    on_s=jnp.where(shed, zero, outs["on_s"]),
+                    cl_s=jnp.where(shed, zero, outs["cl_s"]),
+                    rt=jnp.where(shed, zero, outs["rt"]),
+                    buffer_s=jnp.where(shed, shed_buf, outs["buffer_s"]),
+                    dropped=outs["dropped"] | shed,
+                    shed=shed)
     return new_state, outs
 
 
@@ -409,7 +418,9 @@ class SkyscraperPool:
     and the same bit-exactness contract as the fused engines' carried
     counters. Read it with ``pool.telemetry()`` (active streams, slot
     order) and ``pool.shed_stats()`` (per-stream shed fractions,
-    retired streams included).
+    retired streams included). The host counters (ticks, replans,
+    host pulls, uploads) count with or without it, and every tick runs
+    in ``repro.obs`` spans (see ``process``).
     """
 
     def __init__(self, sky: Skyscraper, n_streams: int, sink=None,
@@ -450,12 +461,11 @@ class SkyscraperPool:
         self._seen = 0
         # last tick's fired standing-query alerts (see ``process``)
         self.alerts = []
-        self._tel = None
         self._retired_tel: Dict[int, Dict] = {}
-        if telemetry:
-            from repro.obs.telemetry import HostTelemetry
-            k0 = int(np.argmin(np.asarray(sky.tables.rank_pos)))
-            self._tel = HostTelemetry(self._cap, k0)
+        # host counters always; per-stream float32 recording on request
+        k0 = int(np.argmin(np.asarray(sky.tables.rank_pos)))
+        self._tel = HostTelemetry(self._cap, k0, record=telemetry)
+        install_gc_spans()
 
     # -- lifecycle -----------------------------------------------------
     @property
@@ -516,8 +526,7 @@ class SkyscraperPool:
         self._slot_of[stream_id] = slot
         self._stream_of[slot] = stream_id
         self._pending_valid[slot] = False
-        if self._tel is not None:
-            self._tel.reset_slot(slot)
+        self._tel.reset_slot(slot)
         return slot
 
     def retire(self, stream_id: int) -> int:
@@ -527,7 +536,7 @@ class SkyscraperPool:
         preserved in ``shed_stats()``. Returns the freed slot."""
         slot = self._slot_of.pop(stream_id)
         del self._stream_of[slot]
-        if self._tel is not None:
+        if self._tel.record:
             self._retired_tel[stream_id] = {
                 "segments": float(self._tel.counters["seg_total"][slot]),
                 "dropped": float(self._tel.counters["seg_dropped"][slot]),
@@ -564,8 +573,7 @@ class SkyscraperPool:
         self._pending_valid = np.concatenate(
             [self._pending_valid, np.zeros(pad, bool)])
         self._free.extend(range(self._cap, new_cap))
-        if self._tel is not None:
-            self._tel.grow(new_cap)
+        self._tel.grow(new_cap)
         self._cap = new_cap
 
     # -- observability -------------------------------------------------
@@ -573,7 +581,7 @@ class SkyscraperPool:
         """Snapshot of the pool's flight recorder (``repro.obs``'s
         ``Telemetry``) restricted to the ACTIVE streams in slot order,
         or None when constructed without one."""
-        if self._tel is None:
+        if not self._tel.record:
             return None
         return self._tel.snapshot(select=sorted(self._stream_of))
 
@@ -582,7 +590,7 @@ class SkyscraperPool:
         ``{stream_id: {segments, dropped, priority}}`` — retired
         streams keep the counters they accumulated while live."""
         out = {}
-        if self._tel is None:
+        if not self._tel.record:
             return out
         prio = np.asarray(self._priority)
         for slot in sorted(self._stream_of):
@@ -603,28 +611,27 @@ class SkyscraperPool:
         ``joint_plan=True``: one stacked priority-weighted LP under a
         shared pool budget (``capacity_core_s`` when set, else the
         per-stream budget times the active count)."""
-        sky = self.sky
+        sky, tel = self.sky, self._tel
         budget = (sky.budget_override
                   if getattr(sky, "budget_override", None)
                   else sky.num_cores * sky.tau)
-        use_model = jnp.asarray(self._seen >= self._hist_len)
-        centers = jnp.asarray(sky.centers, jnp.float32)
+        use_model = tel.put(self._seen >= self._hist_len)
+        centers = tel.put(sky.centers, jnp.float32)
         if self._joint_plan:
             total = (float(self.capacity_core_s)
                      if self.capacity_core_s is not None
                      else float(budget) * max(self.V, 1))
             self._alpha = _pool_replan_stacked(
                 sky.forecaster, self._bufs, centers, sky.tables.cost,
-                jnp.float32(total), use_model, self._active,
+                tel.put(total, jnp.float32), use_model, self._active,
                 self._priority, n_split=sky.n_split,
                 interval=sky.interval)
         else:
             self._alpha = _pool_replan(
                 sky.forecaster, self._bufs, centers, sky.tables.cost,
-                jnp.float32(budget), use_model,
+                tel.put(budget, jnp.float32), use_model,
                 n_split=sky.n_split, interval=sky.interval)
-        if self._tel is not None:
-            self._tel.replans += 1
+        tel.replans += 1
 
     # -- the tick ------------------------------------------------------
     def process(self, segments, arrival_mults: Optional[Sequence] = None):
@@ -635,7 +642,29 @@ class SkyscraperPool:
         gives the ids), or a ``{stream_id: segment}`` dict.
         ``arrival_mults`` likewise (list in slot order or dict).
         Returns ``(statuses, results)`` for the active streams in slot
-        order; a dropped/shed stream's result is None."""
+        order; a dropped/shed stream's result is None.
+
+        Each layer of the tick runs in a ``repro.obs`` span inside
+        ``pool.tick`` (see the table there)."""
+        sink_obs = self.sink.obs if self.sink is not None else None
+        io0 = self._io(sink_obs)
+        with span("pool.tick", t=self._seen) as tick_span:
+            statuses, results = self._tick(segments, arrival_mults)
+            pulls, uploads = (n - n0 for n, n0 in
+                              zip(self._io(sink_obs), io0))
+            tick_span.set_metadata(host_pulls=pulls, uploads=uploads)
+        return statuses, results
+
+    def _io(self, sink_obs):
+        """(host pulls, uploads) so far, the pool's and its sink's."""
+        tel = self._tel
+        if sink_obs is None:
+            return tel.host_pulls, tel.uploads
+        return (tel.host_pulls + sink_obs["host_pulls"],
+                tel.uploads + sink_obs["uploads"])
+
+    def _tick(self, segments, arrival_mults):
+        tel, t = self._tel, self._seen
         slots = sorted(self._stream_of)
         if isinstance(segments, dict):
             segs = [segments[self._stream_of[s]] for s in slots]
@@ -652,54 +681,64 @@ class SkyscraperPool:
             else:
                 arr_np[np.asarray(slots)] = np.asarray(arrival_mults,
                                                        np.float32)
-        dummy = jnp.zeros((self._cap, K), jnp.float32)
-        cap_op = jnp.float32(np.inf if self.capacity_core_s is None
-                             else self.capacity_core_s)
-        wm_op = jnp.float32(np.inf if self.shed_watermark is None
-                            else self.shed_watermark)
-        self.state, outs = _pool_tick(
-            self.state, jnp.asarray(self._pending_q),
-            jnp.asarray(self._pending_valid), dummy,
-            jnp.asarray(arr_np), self._active, self._priority,
-            self._alpha, self.tables, cap_op, wm_op)
-        self._bufs = _pool_shift(self._bufs, outs["c"])
-        # async double-buffering: when this tick closes a planning
-        # window, ENQUEUE the replan dispatch now — before the host
-        # blocks on the decisions — so planning for window t+1 overlaps
-        # the Transform work of window t on the host
-        if (self._seen + 1) % self.sky._plan_every == 0:
-            self._replan()
-        ks = np.asarray(outs["k"])
-        cats = np.asarray(outs["c"])
-        bufs_s = np.asarray(outs["buffer_s"])
-        drops = np.asarray(outs["dropped"])
-        sheds = np.asarray(outs["shed"])
+        with span("pool.dispatch", t=t) as sp:
+            uploads = tel.uploads
+            dummy = jnp.zeros((self._cap, K), jnp.float32)
+            cap_op = tel.put(np.inf if self.capacity_core_s is None
+                             else self.capacity_core_s, jnp.float32)
+            wm_op = tel.put(np.inf if self.shed_watermark is None
+                            else self.shed_watermark, jnp.float32)
+            self.state, outs = _pool_tick(
+                self.state, tel.put(self._pending_q),
+                tel.put(self._pending_valid), dummy,
+                tel.put(arr_np), self._active, self._priority,
+                self._alpha, self.tables, cap_op, wm_op)
+            self._bufs = _pool_shift(self._bufs, outs["c"])
+            # async double-buffering: when this tick closes a planning
+            # window, ENQUEUE the replan dispatch now — before the host
+            # blocks on the decisions — so planning for window t+1
+            # overlaps the Transform work of window t on the host
+            if (self._seen + 1) % self.sky._plan_every == 0:
+                with span("pool.replan", t=t, joint=self._joint_plan):
+                    self._replan()
+            sp.set_metadata(uploads=tel.uploads - uploads)
+        with span("pool.pull", t=t, pulls=6):
+            ks = tel.pull(outs["k"])
+            cats = tel.pull(outs["c"])
+            bufs_s = tel.pull(outs["buffer_s"])
+            drops = tel.pull(outs["dropped"])
+            sheds = tel.pull(outs["shed"])
+            active_np = tel.pull(self._active)
         statuses, results = [], []
         q_np = np.zeros(self._cap, np.float32)
         q_valid = np.zeros(self._cap, bool)
-        for i, slot in enumerate(slots):
-            k = int(ks[slot])
-            status = {"stream_id": self._stream_of[slot],
-                      "config": self.sky.configs[k], "k": k,
-                      "category": int(cats[slot]),
-                      "buffer_s": float(bufs_s[slot]),
-                      "dropped": bool(drops[slot]),
-                      "shed": bool(sheds[slot])}
-            if drops[slot]:
-                # shed/dropped: the segment is NOT transformed (that is
-                # the work the shed saves); quality 0 by contract
-                status["quality"] = 0.0
-                results.append(None)
-            else:
-                result, q = self.sky.proc_fn(segs[i], self.sky.configs[k])
-                q_np[slot] = q
-                q_valid[slot] = True
-                status["quality"] = float(q)
-                results.append(result)
-            statuses.append(status)
-        active_np = np.asarray(self._active)
-        if self._tel is not None:
-            self._tel.update(outs, valid=active_np)
+        with span("pool.transform", t=t) as sp:
+            for i, slot in enumerate(slots):
+                k = int(ks[slot])
+                status = {"stream_id": self._stream_of[slot],
+                          "config": self.sky.configs[k], "k": k,
+                          "category": int(cats[slot]),
+                          "buffer_s": float(bufs_s[slot]),
+                          "dropped": bool(drops[slot]),
+                          "shed": bool(sheds[slot])}
+                if drops[slot]:
+                    # shed/dropped: the segment is NOT transformed (that
+                    # is the work the shed saves); quality 0 by contract
+                    status["quality"] = 0.0
+                    results.append(None)
+                else:
+                    result, q = self.sky.proc_fn(segs[i],
+                                                 self.sky.configs[k])
+                    q_np[slot] = q
+                    q_valid[slot] = True
+                    status["quality"] = float(q)
+                    results.append(result)
+                statuses.append(status)
+            n_done = int(q_valid.sum())
+            sp.set_metadata(transformed=n_done,
+                            dropped=len(slots) - n_done)
+        with span("pool.recorder", t=t) as sp:
+            sp.set_metadata(pulls=tel.update(outs, valid=active_np))
         # measured qualities fold into the NEXT tick's carried state
         # (inside the tick kernel — no extra dispatch)
         self._pending_q = q_np
@@ -708,14 +747,15 @@ class SkyscraperPool:
             # Load: the decision traces are already on device; the only
             # host-born values are the measured qualities themselves.
             # One row per ACTIVE stream, carrying its real stream id.
-            ids = np.zeros(self._cap, np.int64)
-            for slot in slots:
-                ids[slot] = self._stream_of[slot]
-            q_dev = jnp.asarray(q_np)
-            out_vec = (jax.nn.one_hot(outs["k"], K, dtype=jnp.float32)
-                       * q_dev[:, None])
+            with span("pool.load", t=t):
+                ids = np.zeros(self._cap, np.int64)
+                for slot in slots:
+                    ids[slot] = self._stream_of[slot]
+                q_dev = tel.put(q_np)
+                out_vec = (jax.nn.one_hot(outs["k"], K, dtype=jnp.float32)
+                           * q_dev[:, None])
             self.sink.ingest_tick(outs, quality=q_dev, out_vecs=out_vec,
-                                  t=self._seen, stream_ids=ids,
+                                  t=t, stream_ids=ids,
                                   valid=active_np)
             # the tick dispatch above already refreshed any registered
             # standing queries; surface the fired alert masks per tick

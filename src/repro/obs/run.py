@@ -1,31 +1,23 @@
-"""``python -m repro.obs`` — the observability report driver.
+"""``python -m repro.obs`` — the dispatch audit's command line.
 
-Traces every registry engine (see ``repro.obs.trace``), writes
-
-- ``OBS.json``      aggregated per-engine metrics (committed baseline),
-- ``OBS_TRACE.json`` the Chrome-trace span timeline (open in
-  ``chrome://tracing`` or Perfetto; regenerated, not committed),
-
+Audits every registry engine (see ``repro.obs.trace``), writes
+``OBS.json`` (per-engine structural counts, the committed baseline),
 and with ``--compare OLD.json`` exits non-zero on regressions —
-mirroring the ``ANALYSIS.json`` / ``BENCH_*.json`` gating pattern:
+mirroring the ``ANALYSIS.json`` gating pattern:
 
 - **ceilings** (structural, host-independent, zero headroom): a warm
   recompile, a host-transfer op, or extra executables vs baseline;
-- **span-time floors** (timings, host-class-gated like the bench
-  floors): a span that slowed >20% vs baseline fails — but only when
-  both snapshots come from the same host class AND the baseline span
-  is above ``SPAN_FLOOR_US`` (micro-spans are pure noise);
 - a baseline engine that disappears (or degrades to skipped) fails —
   a gate that goes green when its engine vanishes is no gate.
 
 Topology changes (e.g. the forced-8-device tier1 leg) skip per-engine
-numeric gates, exactly like the analysis compare.
+numeric gates, exactly like the analysis compare. Times are the
+profiler's business (``repro.obs.spans``), not this report's.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 from typing import Dict, List
 
@@ -33,24 +25,20 @@ import jax
 
 from repro.obs.trace import trace_all
 
-SCHEMA = 1
-SPAN_FLOOR_US = 5000.0       # gate span growth only above this baseline
-SPAN_GROWTH = 0.20           # >20% slower than baseline fails
+SCHEMA = 2
 _CEILINGS = ("new_executables", "recompiles", "host_transfers")
 
 
 def run_obs(only=None, reps: int = 3, with_hlo: bool = True) -> Dict:
-    """Trace the registry; return ``(report, chrome_trace)``."""
-    records, trace = trace_all(only=only, reps=reps, with_hlo=with_hlo)
-    report = {
+    """Audit the registry; return the report."""
+    records = trace_all(only=only, reps=reps, with_hlo=with_hlo)
+    return {
         "schema": SCHEMA,
         "topology": {"n_devices": jax.device_count()},
-        "host": {"host_cores": float(os.cpu_count() or 1)},
         "engines": records,
         "n_engines": len(records),
         "n_skipped": sum(1 for r in records.values() if "skipped" in r),
     }
-    return report, trace
 
 
 def compare(new: Dict, old: Dict) -> List[str]:
@@ -61,14 +49,6 @@ def compare(new: Dict, old: Dict) -> List[str]:
               f"{new.get('topology')}; skipping per-engine gates",
               file=sys.stderr)
         return regressions
-    old_cores = old.get("host", {}).get("host_cores")
-    new_cores = new.get("host", {}).get("host_cores")
-    same_host = (old_cores is None or new_cores is None
-                 or old_cores == new_cores)
-    if not same_host:
-        print(f"[obs] host class changed ({old_cores:.0f} -> "
-              f"{new_cores:.0f} cores): span floors advisory, "
-              f"ceilings still gated", file=sys.stderr)
     for name, old_rec in sorted(old.get("engines", {}).items()):
         if "skipped" in old_rec:
             continue
@@ -86,19 +66,11 @@ def compare(new: Dict, old: Dict) -> List[str]:
                     and isinstance(nv, (int, float)) and nv > ov:
                 regressions.append(
                     f"{name}: {key} grew {ov} -> {nv} [ceiling]")
-        ov, nv = old_rec.get("span_us"), new_rec.get("span_us")
-        if same_host and isinstance(ov, (int, float)) \
-                and isinstance(nv, (int, float)) \
-                and ov >= SPAN_FLOOR_US \
-                and nv > ov * (1.0 + SPAN_GROWTH):
-            regressions.append(
-                f"{name}: span_us slowed {ov:.0f} -> {nv:.0f} "
-                f"(>{SPAN_GROWTH:.0%}) [floor]")
     return regressions
 
 
 def _summary(report: Dict) -> str:
-    lines = [f"obs: {report['n_engines']} engines traced "
+    lines = [f"obs: {report['n_engines']} engines audited "
              f"({report['n_skipped']} skipped, "
              f"{report['topology']['n_devices']} devices)"]
     for name, rec in report["engines"].items():
@@ -106,9 +78,7 @@ def _summary(report: Dict) -> str:
             lines.append(f"  {name:30s} SKIP ({rec['skipped']})")
             continue
         lines.append(
-            f"  {name:30s} span={rec['span_us']:9.1f}us "
-            f"cold={rec['cold_us']:10.1f}us "
-            f"exec+{rec['new_executables']} "
+            f"  {name:30s} exec+{rec['new_executables']} "
             f"recompile={rec['recompiles']} "
             f"hosttx={rec.get('host_transfers', '?')} "
             f"out={rec['out_bytes']}B")
@@ -116,26 +86,22 @@ def _summary(report: Dict) -> str:
 
 
 def main(argv=None) -> int:
-    """CLI for the dispatch tracer (``python -m repro.obs``): runs every
-    registered engine under the tracer, writes OBS.json + a Chrome
-    trace, and regression-gates against ``--compare``."""
+    """CLI for the dispatch audit (``python -m repro.obs``): runs every
+    registered engine cold and warm, writes OBS.json, and
+    regression-gates against ``--compare``."""
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs",
-        description="dispatch tracer over every registered engine: "
-                    "Chrome-trace spans + regression-gated OBS.json")
+        description="dispatch audit over every registered engine: "
+                    "regression-gated OBS.json")
     ap.add_argument("--json", default="OBS.json",
                     help="report path (default ./OBS.json)")
-    ap.add_argument("--trace", default="OBS_TRACE.json",
-                    help="Chrome-trace output path "
-                         "(default ./OBS_TRACE.json)")
     ap.add_argument("--compare", metavar="OLD",
                     help="fail on regressions vs a baseline OBS.json")
     ap.add_argument("--only", help="substring filter on engine names "
                                    "(debug; compare gates still apply "
-                                   "to the traced subset)")
+                                   "to the audited subset)")
     ap.add_argument("--smoke", action="store_true",
-                    help="single warm rep per engine (CI smoke; "
-                         "structural gates only in practice)")
+                    help="single warm rep per engine (CI smoke)")
     ap.add_argument("--reps", type=int, default=None,
                     help="warm calls per engine (default 3; smoke 1)")
     args = ap.parse_args(argv)
@@ -146,17 +112,13 @@ def main(argv=None) -> int:
             old = json.load(fh)
 
     reps = args.reps if args.reps is not None else (1 if args.smoke else 3)
-    report, trace = run_obs(only=args.only, reps=reps)
+    report = run_obs(only=args.only, reps=reps)
     print(_summary(report))
 
     with open(args.json, "w") as fh:
         json.dump(report, fh, indent=1, sort_keys=True)
         fh.write("\n")
     print(f"[obs] wrote {args.json}")
-    with open(args.trace, "w") as fh:
-        json.dump(trace, fh)
-        fh.write("\n")
-    print(f"[obs] wrote {len(trace['traceEvents'])} spans to {args.trace}")
 
     rc = 0
     if old is not None:

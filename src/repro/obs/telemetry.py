@@ -284,34 +284,62 @@ def telemetry_ref(traces: Dict[str, np.ndarray], k0,
 
 
 class HostTelemetry:
-    """Sequential float32 accumulator over per-tick switch outs — the
-    serving-pool flight recorder. Updates happen host-side from arrays
-    the pool already materializes each tick, so telemetry adds ZERO
-    device dispatches (the extra ``np.asarray`` reads are transfers of
-    already-computed outputs, not new programs)."""
+    """The serving pool's host counters and flight recorder.
 
-    def __init__(self, n_streams: int, k0: int):
+    The integer tallies count whether or not the recorder is on:
+    ``ticks``, ``replans``, ``host_pulls`` (blocking device→host reads
+    of the tick path) and ``uploads`` (host→device puts), counted where
+    the pool pulls and puts (``pull`` / ``put``).
+
+    With ``record=True`` it is also a sequential float32 accumulator
+    over per-tick switch outs. Updates happen host-side from arrays the
+    pool already materializes each tick, so telemetry adds ZERO device
+    dispatches (its extra reads are transfers of already-computed
+    outputs, not new programs, and count in ``host_pulls``)."""
+
+    def __init__(self, n_streams: int, k0: int, record: bool = True):
         self.V = int(n_streams)
         self.k0 = int(k0)
+        self.record = bool(record)
         self.counters = {k: np.zeros((self.V,), np.float32)
-                         for k in TEL_KEYS}
+                         for k in TEL_KEYS} if record else {}
         self._k_prev = np.full((self.V,), int(k0), np.int64)
         self.ticks = 0
         self.replans = 0
+        self.host_pulls = 0
+        self.uploads = 0
 
-    def update(self, outs, valid=None) -> None:
+    def pull(self, x) -> np.ndarray:
+        """``np.asarray`` of a device value, counted as one host pull."""
+        self.host_pulls += 1
+        return np.asarray(x)
+
+    def put(self, x, dtype=None) -> jnp.ndarray:
+        """``jnp.asarray``, counted as one upload when ``x`` is on the
+        host."""
+        self.uploads += not isinstance(x, jax.Array)
+        return jnp.asarray(x, dtype)
+
+    def update(self, outs, valid=None) -> int:
         """One pool tick: ``outs`` is the ``switch_step_multi`` outs
         dict ((V,) leaves, device or host). ``valid`` (V,) bool masks
         slots that took no step this tick (the elastic pool's
         retired/empty slots) — their counters are untouched, matching
-        the fused engines' masked-step no-op contract."""
+        the fused engines' masked-step no-op contract. Without
+        ``record`` only the tick is counted. Returns the host pulls it
+        made: ``on_s`` and ``cl_s`` (the pool has already pulled ``k``,
+        ``dropped`` and ``buffer_s``, whose host copies are cached)."""
+        self.ticks += 1
+        if not self.record:
+            return 0
+        pulls = self.host_pulls
         self._k_prev = _accumulate(
             self.counters, self._k_prev, np.asarray(outs["k"]),
             np.asarray(outs["dropped"]), np.asarray(outs["buffer_s"]),
-            np.asarray(outs["on_s"]), np.asarray(outs["cl_s"]),
+            self.pull(outs["on_s"]), self.pull(outs["cl_s"]),
             np.ones((self.V,), bool) if valid is None
             else np.asarray(valid, bool))
-        self.ticks += 1
+        return self.host_pulls - pulls
 
     def grow(self, n_streams: int) -> None:
         """Widen the stream axis to ``n_streams`` slots (elastic-pool
@@ -347,7 +375,9 @@ class HostTelemetry:
         return Telemetry(
             counters=counters,
             extras={"ticks": float(self.ticks),
-                    "replans": float(self.replans)})
+                    "replans": float(self.replans),
+                    "host_pulls": float(self.host_pulls),
+                    "uploads": float(self.uploads)})
 
 
 # ---------------------------------------------------------------------------
@@ -382,6 +412,9 @@ class StoreTelemetry:
     standing_refreshes: int = 0
     alerts_checked: int = 0
     alerts_fired: int = 0
+    # host<->device transfers of the tick ingest and the alert poll
+    host_pulls: int = 0
+    uploads: int = 0
 
     @property
     def n_rows(self) -> int:
@@ -420,7 +453,15 @@ def store_obs_init() -> Dict[str, int]:
     return {"ingest_dispatches": 0, "query_dispatches": 0,
             "lag_rows": 0, "lag_sum_ticks": 0, "lag_max_ticks": 0,
             "standing_queries": 0, "standing_refreshes": 0,
-            "alerts_checked": 0, "alerts_fired": 0}
+            "alerts_checked": 0, "alerts_fired": 0,
+            "host_pulls": 0, "uploads": 0}
+
+
+def store_put(obs: Dict[str, int], x, dtype=None) -> jnp.ndarray:
+    """``jnp.asarray`` for a store kernel operand, counted in
+    ``obs["uploads"]`` when ``x`` is on the host."""
+    obs["uploads"] += not isinstance(x, jax.Array)
+    return jnp.asarray(x, dtype)
 
 
 def store_obs_batch(obs: Dict[str, int], n_streams: int, T: int) -> None:

@@ -1,25 +1,18 @@
-"""Host-side dispatch tracing over the analysis registry.
+"""Dispatch audit over the analysis registry.
 
-Every engine the PR-5 auditor verifies is also *traceable*: the tracer
-builds the engine's tiny example, runs it with wall-clock spans around
-the cold (compile) and warm calls, brackets each call with the engine's
-jit-cache probe (so a recompile shows up as a counted event, not a
-mystery latency), sizes the argument/output pytrees, and counts
-host-transfer ops in the compiled HLO. Spans are emitted in Chrome
-trace-event format (load ``OBS_TRACE.json`` in ``chrome://tracing`` /
-Perfetto) and aggregated into the ``OBS.json`` report that
-``python -m repro.obs --compare`` gates regressions against.
-
-Scanner ships per-stage profiling as a first-class feature of its
-pipeline runtime; this is the equivalent for a stack whose "stages"
-are compiled programs — the unit of observation is the dispatch.
+Every engine the static auditor verifies is also *traceable*: this
+module builds the engine's tiny example, runs it cold (compile) and
+warm, brackets each call with the engine's jit-cache probe (so a
+recompile shows up as a counted event, not a mystery latency), sizes
+the argument/output pytrees, and counts host-transfer ops in the
+compiled HLO. The records make the ``OBS.json`` report that
+``python -m repro.obs --compare`` gates regressions against. Times are
+not recorded here: a time comes from a ``jax.profiler`` trace of the
+served path on the device (see ``repro.obs.spans``).
 """
 from __future__ import annotations
 
-import json
-import statistics
-import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 
@@ -56,30 +49,11 @@ def _host_transfer_count(ex: registry.EngineExample) -> int:
     return sum(1 for v in violations if v["check"] == "host_transfer")
 
 
-class SpanRecorder:
-    """Collects Chrome trace events against one wall-clock origin."""
-
-    def __init__(self):
-        self.origin = time.perf_counter()
-        self.events: List[Dict] = []
-
-    def span(self, name: str, cat: str, t_start: float, t_end: float,
-             tid: int, args: Optional[Dict] = None) -> None:
-        self.events.append({
-            "name": name, "cat": cat, "ph": "X",
-            "ts": (t_start - self.origin) * 1e6,
-            "dur": max((t_end - t_start) * 1e6, 0.01),
-            "pid": 0, "tid": tid, "args": args or {}})
-
-    def chrome_trace(self) -> Dict:
-        return {"traceEvents": self.events, "displayTimeUnit": "ms"}
-
-
-def trace_engine(name: str, engine: registry.Engine, rec: SpanRecorder,
-                 tid: int, reps: int = 3, with_hlo: bool = True) -> Dict:
-    """Trace one engine: cold span (compile + first run), ``reps`` warm
-    spans, probe deltas, byte sizes, host-transfer count. Returns the
-    engine's OBS.json record."""
+def trace_engine(engine: registry.Engine, reps: int = 3,
+                 with_hlo: bool = True) -> Dict:
+    """Audit one engine: a cold call (compile + first run), ``reps``
+    warm calls, probe deltas, byte sizes, host-transfer count. Returns
+    the engine's OBS.json record."""
     try:
         ex = engine.build()
     except registry.SkipEngine as e:
@@ -87,30 +61,16 @@ def trace_engine(name: str, engine: registry.Engine, rec: SpanRecorder,
 
     probe = engine.probe or (lambda: 0)
     p0 = probe()
-    t0 = time.perf_counter()
     out = jax.block_until_ready(ex.fn(*ex.args, **ex.kwargs))
-    t1 = time.perf_counter()
     p1 = probe()
-    rec.span(f"{name}:cold", "compile+run", t0, t1, tid,
-             {"new_executables": p1 - p0})
 
-    spans_us = []
     recompiles = 0
-    for i in range(max(reps, 1)):
+    for _ in range(max(reps, 1)):
         q0 = probe()
-        s0 = time.perf_counter()
         out = jax.block_until_ready(ex.fn(*ex.args, **ex.kwargs))
-        s1 = time.perf_counter()
-        q1 = probe()
-        recompiles += q1 - q0
-        spans_us.append((s1 - s0) * 1e6)
-        rec.span(name, "dispatch", s0, s1, tid,
-                 {"call": i, "recompiles": q1 - q0})
+        recompiles += probe() - q0
 
     record = {
-        "cold_us": (t1 - t0) * 1e6,
-        "span_us": statistics.median(spans_us),
-        "span_min_us": min(spans_us),
         "new_executables": int(p1 - p0),
         "recompiles": int(recompiles),
         "arg_bytes": _tree_bytes((ex.args, ex.kwargs)),
@@ -122,36 +82,12 @@ def trace_engine(name: str, engine: registry.Engine, rec: SpanRecorder,
 
 
 def trace_all(only: Optional[str] = None, reps: int = 3,
-              with_hlo: bool = True) -> Tuple[Dict[str, Dict], Dict]:
-    """Trace every registered engine (optionally substring-filtered).
-    Returns ``(records, chrome_trace)``."""
+              with_hlo: bool = True) -> Dict[str, Dict]:
+    """Audit every registered engine (optionally substring-filtered);
+    returns the per-engine records."""
     registry.import_engine_modules()
     engines = registry.engines()
     if only:
         engines = {k: v for k, v in engines.items() if only in k}
-    rec = SpanRecorder()
-    records: Dict[str, Dict] = {}
-    for tid, (name, engine) in enumerate(engines.items()):
-        records[name] = trace_engine(name, engine, rec, tid, reps=reps,
-                                     with_hlo=with_hlo)
-    return records, rec.chrome_trace()
-
-
-def validate_chrome_trace(trace: Dict) -> List[str]:
-    """Structural problems of a Chrome trace dict (empty list = valid:
-    serializable, required keys present, durations non-negative)."""
-    problems = []
-    events = trace.get("traceEvents")
-    if not isinstance(events, list):
-        return ["traceEvents missing or not a list"]
-    for i, ev in enumerate(events):
-        for key in ("name", "ph", "ts", "pid", "tid"):
-            if key not in ev:
-                problems.append(f"event {i}: missing {key!r}")
-        if ev.get("ph") == "X" and ev.get("dur", 0) < 0:
-            problems.append(f"event {i}: negative dur")
-    try:
-        json.dumps(trace)
-    except (TypeError, ValueError) as e:
-        problems.append(f"not JSON-serializable: {e}")
-    return problems
+    return {name: trace_engine(engine, reps=reps, with_hlo=with_hlo)
+            for name, engine in engines.items()}

@@ -76,6 +76,7 @@ from repro.analysis.registry import example_builder, register_engine
 from repro.core.switcher import register_cache_probe
 from repro.kernels.warehouse_agg import CMP as _CMP
 from repro.kernels.warehouse_agg import FusedAggSpec, fused_segment_agg
+from repro.obs.spans import span
 from repro.warehouse.query import (Filter, GroupBy, MultiGroupBy, TopK,
                                    WindowAgg, _apply_nodes, _FilterRef,
                                    _pallas_spec, _resolve_use_pallas,
@@ -249,8 +250,9 @@ def _answer_kernel(state, fvals, *, spec, sharded):
         table, mask = _seg_table(node, out, cnt)
         return _apply_nodes(table, mask, fv, post)
 
-    return jax.vmap(one, in_axes=(1, 0) if sharded else (0, 0))(
-        state, fvals)
+    with jax.named_scope("sink.answer"):
+        return jax.vmap(one, in_axes=(1, 0) if sharded else (0, 0))(
+            state, fvals)
 
 
 # ---------------------------------------------------------------------------
@@ -539,27 +541,36 @@ class StandingQueries:
         """Evaluate every subscription against its plan's CURRENT
         standing answer: one answer dispatch per plan shape, then the
         predicates host-side over the fixed-shape tables. Updates the
-        flight-recorder counters (``alerts_checked``/``alerts_fired``)."""
+        flight-recorder counters (``alerts_checked``/``alerts_fired``,
+        and ``host_pulls`` for the answer columns read), inside a
+        ``sink.alert_poll`` span."""
         alerts: List[Alert] = []
         cache: Dict[int, tuple] = {}
-        for sub in self._subs.values():
-            q = self._queries[sub.handle]
-            g = self._group_of(q)
-            if id(g) not in cache:
-                cache[id(g)] = self.group_answers(g)
-            table, mask = cache[id(g)]
-            row = {k: np.asarray(v[q.slot]) for k, v in table.items()}
-            valid = np.asarray(mask[q.slot])
-            col = row[sub.predicate.column]
-            dt = np.float64 if np.issubdtype(col.dtype, np.integer) \
-                else np.float32
-            pred = np.asarray(_CMP[sub.predicate.op](
-                col.astype(dt), dt(sub.predicate.value)))
-            fired = valid & pred
-            self.host.obs["alerts_checked"] += 1
-            self.host.obs["alerts_fired"] += int(fired.sum())
-            alerts.append(Alert(sub.sid, sub.name, sub.handle, fired,
-                                row))
+        obs = self.host.obs
+        pulls, fired_n = obs["host_pulls"], obs["alerts_fired"]
+        with span("sink.alert_poll",
+                  subscriptions=len(self._subs)) as sp:
+            for sub in self._subs.values():
+                q = self._queries[sub.handle]
+                g = self._group_of(q)
+                if id(g) not in cache:
+                    cache[id(g)] = self.group_answers(g)
+                table, mask = cache[id(g)]
+                row = {k: np.asarray(v[q.slot]) for k, v in table.items()}
+                valid = np.asarray(mask[q.slot])
+                obs["host_pulls"] += len(row) + 1
+                col = row[sub.predicate.column]
+                dt = np.float64 if np.issubdtype(col.dtype, np.integer) \
+                    else np.float32
+                pred = np.asarray(_CMP[sub.predicate.op](
+                    col.astype(dt), dt(sub.predicate.value)))
+                fired = valid & pred
+                obs["alerts_checked"] += 1
+                obs["alerts_fired"] += int(fired.sum())
+                alerts.append(Alert(sub.sid, sub.name, sub.handle, fired,
+                                    row))
+            sp.set_metadata(pulls=obs["host_pulls"] - pulls,
+                            fired=obs["alerts_fired"] - fired_n)
         return alerts
 
 

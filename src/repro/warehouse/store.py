@@ -60,8 +60,9 @@ from repro.analysis.registry import example_builder, register_engine
 from repro.core.switcher import register_cache_probe
 from repro.distribution.sharding import put_row_sharded
 from repro.launch.mesh import make_shard_mesh
+from repro.obs.spans import span
 from repro.obs.telemetry import (StoreTelemetry, store_obs_batch,
-                                 store_obs_init, store_obs_tick)
+                                 store_obs_init, store_obs_tick, store_put)
 
 # repro.warehouse.standing's fold — imported lazily (inside the ingest
 # kernels, only on the sspecs != () trace path) because standing.py
@@ -220,14 +221,16 @@ def _ingest_tick_masked(cols, traces, quality, out_vecs, t, offset,
     upd[OUT_COLUMN] = out_vecs
     keep = jnp.asarray(valid, bool)
     cap = next(iter(cols.values())).shape[0]
-    rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    idx = jnp.where(keep, offset + rank, cap)
-    new = {k: cols[k].at[idx].set(upd[k].astype(cols[k].dtype),
-                                  mode="drop") for k in cols}
+    with jax.named_scope("sink.write"):
+        rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
+        idx = jnp.where(keep, offset + rank, cap)
+        new = {k: cols[k].at[idx].set(upd[k].astype(cols[k].dtype),
+                                      mode="drop") for k in cols}
     if not sspecs:
         return new
-    cast = {k: v.astype(cols[k].dtype) for k, v in upd.items()}
-    states = _fold_all(sstates, sfvals, cast, keep, sspecs)
+    with jax.named_scope("sink.fold"):
+        cast = {k: v.astype(cols[k].dtype) for k, v in upd.items()}
+        states = _fold_all(sstates, sfvals, cast, keep, sspecs)
     return new, states
 
 
@@ -260,9 +263,11 @@ class SegmentStore:
         cap = _bucket_cap(need, self.chunk_rows)
         # pad in place of zeros + update: a grown 2^26-row store is
         # 6 GiB on a v5e, and old + zeros + grown would not fit in HBM
-        self.columns = {
-            k: jnp.pad(v, ((0, cap - v.shape[0]),) + ((0, 0),) * (v.ndim - 1))
-            for k, v in self.columns.items()}
+        with span("sink.grow", capacity=cap):
+            self.columns = {
+                k: jnp.pad(v, ((0, cap - v.shape[0]),)
+                           + ((0, 0),) * (v.ndim - 1))
+                for k, v in self.columns.items()}
 
     # -- ingestion -----------------------------------------------------
     def ingest_fused(self, traces, out_vecs, *, stream_id: int = 0,
@@ -329,28 +334,31 @@ class SegmentStore:
         assert out_vecs.ndim == 2 and out_vecs.shape[1] == self.out_dim
         keep = None if valid is None else np.asarray(valid, bool)
         n_new = V if keep is None else int(keep.sum())
-        self._reserve(n_new)
-        sub = {src: traces[src] for src, _ in _RUN_KEYS}
-        sstates, sfvals, sspecs = _standing_args(self)
-        if stream_ids is None and keep is None:
-            res = _ingest_tick(
-                self.columns, sub, jnp.asarray(quality, jnp.float32),
-                jnp.asarray(out_vecs, jnp.float32), jnp.int32(t),
-                jnp.int32(self.n_rows), sstates, sfvals, sspecs=sspecs)
-        else:
-            ids = (np.arange(V) if stream_ids is None
-                   else np.asarray(stream_ids))
-            res = _ingest_tick_masked(
-                self.columns, sub, jnp.asarray(quality, jnp.float32),
-                jnp.asarray(out_vecs, jnp.float32), jnp.int32(t),
-                jnp.int32(self.n_rows), jnp.asarray(ids, jnp.int32),
-                jnp.asarray(np.ones(V, bool) if keep is None else keep),
-                sstates, sfvals, sspecs=sspecs)
-        if sspecs:
-            self.columns, states = res
-            self.standing.absorb(states)
-        else:
-            self.columns = res
+        with span("sink.ingest", t=t, rows=n_new):
+            self._reserve(n_new)
+            sub = {src: traces[src] for src, _ in _RUN_KEYS}
+            sstates, sfvals, sspecs = _standing_args(self)
+            obs = self.obs
+            args = (self.columns, sub,
+                    store_put(obs, quality, jnp.float32),
+                    store_put(obs, out_vecs, jnp.float32),
+                    store_put(obs, t, jnp.int32),
+                    store_put(obs, self.n_rows, jnp.int32))
+            if stream_ids is None and keep is None:
+                res = _ingest_tick(*args, sstates, sfvals, sspecs=sspecs)
+            else:
+                ids = (np.arange(V) if stream_ids is None
+                       else np.asarray(stream_ids))
+                res = _ingest_tick_masked(
+                    *args, store_put(obs, ids, jnp.int32),
+                    store_put(obs, np.ones(V, bool) if keep is None
+                              else keep),
+                    sstates, sfvals, sspecs=sspecs)
+            if sspecs:
+                self.columns, states = res
+                self.standing.absorb(states)
+            else:
+                self.columns = res
         self.n_rows += n_new
         if n_new:
             self.t_max = max(self.t_max, t)
@@ -527,13 +535,16 @@ def _append_traced(cols, n_rows, upd, mesh, n_shards, sstates=(),
     if mesh is None:
         sids = jnp.arange(n_shards, dtype=jnp.int32)
         if not sspecs:
-            return jax.vmap(lambda c, nr, s: _route_write(
-                c, nr, upd, owner, s))(cols, n_rows, sids)
+            with jax.named_scope("sink.write"):
+                return jax.vmap(lambda c, nr, s: _route_write(
+                    c, nr, upd, owner, s))(cols, n_rows, sids)
 
         def one(c, nr, s, sts):
-            new, nn = _route_write(c, nr, upd, owner, s)
-            cast = {k: upd[k].astype(c[k].dtype) for k in upd}
-            states = _fold_all(sts, sfvals, cast, owner == s, sspecs)
+            with jax.named_scope("sink.write"):
+                new, nn = _route_write(c, nr, upd, owner, s)
+            with jax.named_scope("sink.fold"):
+                cast = {k: upd[k].astype(c[k].dtype) for k in upd}
+                states = _fold_all(sts, sfvals, cast, owner == s, sspecs)
             return new, nn, states
 
         return jax.vmap(one)(cols, n_rows, sids, sstates)
@@ -541,13 +552,15 @@ def _append_traced(cols, n_rows, upd, mesh, n_shards, sstates=(),
     def body(c, nr, u, ow, sts, fvs):
         c0 = {k: v[0] for k, v in c.items()}
         sid = jax.lax.axis_index("shard")
-        new, n2 = _route_write(c0, nr[0], u, ow, sid)
+        with jax.named_scope("sink.write"):
+            new, n2 = _route_write(c0, nr[0], u, ow, sid)
         stacked = {k: v[None] for k, v in new.items()}
         if not sspecs:
             return stacked, n2[None]
-        cast = {k: u[k].astype(c0[k].dtype) for k in u}
-        states = _fold_all(jax.tree.map(lambda x: x[0], sts), fvs,
-                           cast, ow == sid, sspecs)
+        with jax.named_scope("sink.fold"):
+            cast = {k: u[k].astype(c0[k].dtype) for k in u}
+            states = _fold_all(jax.tree.map(lambda x: x[0], sts), fvs,
+                               cast, ow == sid, sspecs)
         return stacked, n2[None], jax.tree.map(lambda x: x[None], states)
 
     out_specs = (P("shard"), P("shard")) if not sspecs \
@@ -734,9 +747,11 @@ class ShardedStore:
             return
         cap = _bucket_cap(need, self.chunk_rows)
         pad = cap - self.capacity
-        grown = {k: jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-                 for k, v in self.columns.items()}
-        self.columns = self._put(grown)
+        with span("sink.grow", capacity=cap):
+            grown = {k: jnp.pad(v, ((0, 0), (0, pad))
+                                + ((0, 0),) * (v.ndim - 2))
+                     for k, v in self.columns.items()}
+            self.columns = self._put(grown)
 
     # -- ingestion -----------------------------------------------------
     def _owner_counts(self, stream_ids) -> np.ndarray:
@@ -794,16 +809,9 @@ class ShardedStore:
         single routed dispatch (see ``SegmentStore.ingest_tick``)."""
         V = int(out_vecs.shape[0])
         assert out_vecs.ndim == 2 and out_vecs.shape[1] == self.out_dim
-        sub = {src: traces[src] for src, _ in _RUN_KEYS}
-        sstates, sfvals, sspecs = _standing_args(self)
         if stream_ids is None and valid is None:
+            ids = keep = None
             counts = self._owner_counts(np.arange(V))
-            self._reserve(counts)
-            kern = _shard_kernel("tick", self.mesh, self.n_shards)
-            res = kern(self.columns, self.n_rows_dev, sub,
-                       jnp.asarray(quality, jnp.float32),
-                       jnp.asarray(out_vecs, jnp.float32), jnp.int32(t),
-                       sstates, sfvals, sspecs=sspecs)
         else:
             ids = (np.arange(V) if stream_ids is None
                    else np.asarray(stream_ids))
@@ -812,20 +820,30 @@ class ShardedStore:
             counts = np.bincount(ids[keep].astype(np.int64)
                                  % self.n_shards,
                                  minlength=self.n_shards)
-            self._reserve(counts)
-            kern = _shard_kernel("tick_ids", self.mesh, self.n_shards)
-            res = kern(self.columns, self.n_rows_dev, sub,
-                       jnp.asarray(quality, jnp.float32),
-                       jnp.asarray(out_vecs, jnp.float32), jnp.int32(t),
-                       jnp.asarray(ids, jnp.int32), jnp.asarray(keep),
-                       sstates, sfvals, sspecs=sspecs)
-        if sspecs:
-            self.columns, self.n_rows_dev, states = res
-            self.standing.absorb(states)
-        else:
-            self.columns, self.n_rows_dev = res
-        self.n_rows_by_shard += counts
         n_new = int(counts.sum())
+        with span("sink.ingest", t=t, rows=n_new):
+            self._reserve(counts)
+            sub = {src: traces[src] for src, _ in _RUN_KEYS}
+            sstates, sfvals, sspecs = _standing_args(self)
+            obs = self.obs
+            args = (self.columns, self.n_rows_dev, sub,
+                    store_put(obs, quality, jnp.float32),
+                    store_put(obs, out_vecs, jnp.float32),
+                    store_put(obs, t, jnp.int32))
+            if ids is None:
+                kern = _shard_kernel("tick", self.mesh, self.n_shards)
+                res = kern(*args, sstates, sfvals, sspecs=sspecs)
+            else:
+                kern = _shard_kernel("tick_ids", self.mesh, self.n_shards)
+                res = kern(*args, store_put(obs, ids, jnp.int32),
+                           store_put(obs, keep), sstates, sfvals,
+                           sspecs=sspecs)
+            if sspecs:
+                self.columns, self.n_rows_dev, states = res
+                self.standing.absorb(states)
+            else:
+                self.columns, self.n_rows_dev = res
+        self.n_rows_by_shard += counts
         if n_new:
             self.t_max = max(self.t_max, t)
         store_obs_tick(self.obs, n_new)
