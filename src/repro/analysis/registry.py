@@ -45,6 +45,17 @@ class EngineExample(NamedTuple):
     args: Tuple[Any, ...]
     kwargs: Dict[str, Any] = {}
 
+    def run(self):
+        """One call of the engine on fresh copies of its array
+        operands: a kernel that donates an input (the store's write
+        kernels donate their columns) consumes what it is given, and
+        the auditors call one example more than once."""
+        import jax
+        args = jax.tree.map(
+            lambda x: x.copy() if isinstance(x, jax.Array) else x,
+            self.args)
+        return self.fn(*args, **self.kwargs)
+
 
 class Engine(NamedTuple):
     name: str

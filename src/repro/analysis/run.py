@@ -77,9 +77,9 @@ def audit_engine(engine: registry.Engine) -> Dict:
             "path": engine.name})
     else:
         p0 = engine.probe()
-        jax.block_until_ready(ex.fn(*ex.args, **ex.kwargs))
+        jax.block_until_ready(ex.run())
         p1 = engine.probe()
-        jax.block_until_ready(ex.fn(*ex.args, **ex.kwargs))
+        jax.block_until_ready(ex.run())
         p2 = engine.probe()
         new_exec, recompiles = p1 - p0, p2 - p1
         record["dispatch"] = {"new_executables": new_exec,

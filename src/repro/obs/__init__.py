@@ -6,7 +6,8 @@
    recompiles to the warm path (an auditor-pinned invariant).
 2. **Counters** — the serving pool's ``HostTelemetry`` (ticks,
    replans, blocking device→host reads ``host_pulls``, host→device
-   puts ``uploads``) and the store's ``obs`` dict (dispatches, standing
+   puts ``uploads``) and the store's ``obs`` dict (dispatches, the
+   ``donated_dispatches`` among them that wrote in place, standing
    refreshes, alerts, and its own ``host_pulls``/``uploads``). They
    count whether or not the pool records per-stream telemetry.
 3. **Spans** — ``span(name, **counts)``, a ``jax.profiler``
@@ -31,8 +32,8 @@
    ``pool.recorder``       ``HostTelemetry.update``             ``pulls``
    ``pool.load``           the sink's rows: stream ids,         ``t``
                            measured qualities, output vectors
-   ``sink.ingest``         host side of a store's               ``rows``
-                           ``ingest_tick``
+   ``sink.ingest``         host side of a store's               ``rows``, ``donated`` (1 when the
+                           ``ingest_tick``                      write consumed the old columns)
    ``sink.grow``           the store growing a capacity rung    ``capacity``
                            (in ingest)
    ``sink.alert_poll``     ``StandingQueries.poll``             ``subscriptions``, ``pulls``, ``fired``

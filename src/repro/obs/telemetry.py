@@ -415,6 +415,10 @@ class StoreTelemetry:
     # host<->device transfers of the tick ingest and the alert poll
     host_pulls: int = 0
     uploads: int = 0
+    # writes that consumed (donated) the columns they were given, so
+    # XLA landed the rows in place instead of copying the store; falls
+    # behind ingest_dispatches where XLA declined, 0 on a ShardedStore
+    donated_dispatches: int = 0
 
     @property
     def n_rows(self) -> int:
@@ -440,6 +444,7 @@ class StoreTelemetry:
                 f"lag_mean={self.lag_mean_ticks:.1f}t "
                 f"lag_max={self.lag_max_ticks}t "
                 f"ingests={self.ingest_dispatches} "
+                f"donated={self.donated_dispatches} "
                 f"queries={self.query_dispatches} "
                 f"spills={self.spill_events} "
                 f"dequantizes={self.dequantize_events} "
@@ -454,7 +459,7 @@ def store_obs_init() -> Dict[str, int]:
             "lag_rows": 0, "lag_sum_ticks": 0, "lag_max_ticks": 0,
             "standing_queries": 0, "standing_refreshes": 0,
             "alerts_checked": 0, "alerts_fired": 0,
-            "host_pulls": 0, "uploads": 0}
+            "host_pulls": 0, "uploads": 0, "donated_dispatches": 0}
 
 
 def store_put(obs: Dict[str, int], x, dtype=None) -> jnp.ndarray:

@@ -61,13 +61,13 @@ def trace_engine(engine: registry.Engine, reps: int = 3,
 
     probe = engine.probe or (lambda: 0)
     p0 = probe()
-    out = jax.block_until_ready(ex.fn(*ex.args, **ex.kwargs))
+    out = jax.block_until_ready(ex.run())
     p1 = probe()
 
     recompiles = 0
     for _ in range(max(reps, 1)):
         q0 = probe()
-        out = jax.block_until_ready(ex.fn(*ex.args, **ex.kwargs))
+        out = jax.block_until_ready(ex.run())
         recompiles += probe() - q0
 
     record = {

@@ -780,7 +780,9 @@ def execute_sharded(store, plan, *, compressed: bool = False, key=None,
 
 def _source(store):
     """(columns, n_rows) from a SegmentStore, a TieredStore (which
-    materializes its cold tier on device), or a raw (columns, n) pair."""
+    materializes its cold tier on device), or a raw (columns, n) pair.
+    Taken afresh for each query: the store's next write consumes the
+    columns it returns."""
     if hasattr(store, "materialize"):
         return store.materialize()
     if hasattr(store, "columns") and hasattr(store, "n_rows"):

@@ -29,9 +29,27 @@ segment. ``ingest_fused_multi`` does the same for the (n_w, V, W)
 multi-stream traces and ``ingest_tick`` lands one row per live stream
 from a serving-pool tick.
 
+**Ownership: a write consumes the columns it is given.** The store owns
+its columns, and every ``SegmentStore`` write kernel (``_scatter``,
+``_scatter_fold``, ``_ingest_fused``, ``_ingest_fused_multi``,
+``_ingest_tick``, ``_ingest_tick_masked``) donates the column pytree:
+XLA lands the new rows in those buffers in place instead of copying the
+whole store, and returns them as the new columns. A reference to
+``store.columns``, or to any column in it, taken before a write is dead
+after it (the old arrays read ``is_deleted()``; using one raises). Take
+the reference after the write, or copy first
+(``jax.tree.map(jnp.copy, store.columns)``) where a second owner needs
+the old state. The store's ``obs["donated_dispatches"]`` counts the
+writes that consumed their input; where XLA declines (on the CPU a host
+``np.asarray`` view held across a write makes it copy instead) it falls
+behind ``ingest_dispatches``. ``ShardedStore`` does not donate (its
+count stays 0).
+
 The store is a registered JAX pytree (columns are leaves; row count and
 chunking are static aux), so it passes through jit/vmap and flattens
-for checkpointing (see ``warehouse.tiers``).
+for checkpointing (see ``warehouse.tiers``). A store unflattened from
+another's leaves shares their buffers, so a write to either consumes
+them for both: copy the leaves to fork a store.
 
 ``ShardedStore`` is the horizontal scale-out of the same layout: rows
 partition by ``stream_id % n_shards`` onto a 1-D ``('shard',)`` device
@@ -131,7 +149,7 @@ def _put_all(cols, upd, offset):
     return {k: put(cols[k], upd[k]) for k in cols}
 
 
-_scatter = jax.jit(_put_all)
+_scatter = jax.jit(_put_all, donate_argnames=("cols",))
 
 
 def _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs):
@@ -152,14 +170,16 @@ def _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs):
     return new, states
 
 
-@functools.partial(jax.jit, static_argnames=("sspecs",))
+@functools.partial(jax.jit, static_argnames=("sspecs",),
+                   donate_argnames=("cols",))
 def _scatter_fold(cols, upd, offset, sstates, sfvals, *, sspecs):
     """``append_rows`` + standing refresh in one dispatch (the plain
     ``_scatter`` stays the no-registry fast path)."""
     return _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs)
 
 
-@functools.partial(jax.jit, static_argnames=("T", "sspecs"))
+@functools.partial(jax.jit, static_argnames=("T", "sspecs"),
+                   donate_argnames=("cols",))
 def _ingest_fused(cols, traces, out_vecs, stream_id, t0, offset,
                   sstates=(), sfvals=(), *, T, sspecs=()):
     """One device op: flatten the fused engine's stacked (n_w, W) traces,
@@ -172,7 +192,8 @@ def _ingest_fused(cols, traces, out_vecs, stream_id, t0, offset,
     return _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs)
 
 
-@functools.partial(jax.jit, static_argnames=("T", "sspecs"))
+@functools.partial(jax.jit, static_argnames=("T", "sspecs"),
+                   donate_argnames=("cols",))
 def _ingest_fused_multi(cols, traces, out_vecs, stream_base, t0, offset,
                         sstates=(), sfvals=(), *, T, sspecs=()):
     """Multi-stream ingest: traces have (n_w, V, W) leaves; rows land
@@ -190,7 +211,8 @@ def _ingest_fused_multi(cols, traces, out_vecs, stream_base, t0, offset,
     return _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs)
 
 
-@functools.partial(jax.jit, static_argnames=("sspecs",))
+@functools.partial(jax.jit, static_argnames=("sspecs",),
+                   donate_argnames=("cols",))
 def _ingest_tick(cols, traces, quality, out_vecs, t, offset,
                  sstates=(), sfvals=(), *, sspecs=()):
     """One serving-pool tick: V rows (one per live stream)."""
@@ -203,7 +225,8 @@ def _ingest_tick(cols, traces, quality, out_vecs, t, offset,
     return _write_and_fold(cols, upd, offset, sstates, sfvals, sspecs)
 
 
-@functools.partial(jax.jit, static_argnames=("sspecs",))
+@functools.partial(jax.jit, static_argnames=("sspecs",),
+                   donate_argnames=("cols",))
 def _ingest_tick_masked(cols, traces, quality, out_vecs, t, offset,
                         stream_ids, valid, sstates=(), sfvals=(), *,
                         sspecs=()):
@@ -282,15 +305,12 @@ class SegmentStore:
         self._reserve(T)
         sub = {src: traces[src] for src, _ in _RUN_KEYS}
         sstates, sfvals, sspecs = _standing_args(self)
+        old = self.columns
         res = _ingest_fused(
-            self.columns, sub, jnp.asarray(out_vecs, jnp.float32),
+            old, sub, jnp.asarray(out_vecs, jnp.float32),
             jnp.int32(stream_id), jnp.int32(t0), jnp.int32(self.n_rows),
             sstates, sfvals, T=T, sspecs=sspecs)
-        if sspecs:
-            self.columns, states = res
-            self.standing.absorb(states)
-        else:
-            self.columns = res
+        self._land(old, res, sspecs)
         self.n_rows += T
         self.t_max = max(self.t_max, t0 + T - 1)
         store_obs_batch(self.obs, 1, T)
@@ -305,15 +325,12 @@ class SegmentStore:
         self._reserve(V * T)
         sub = {src: traces[src] for src, _ in _RUN_KEYS}
         sstates, sfvals, sspecs = _standing_args(self)
+        old = self.columns
         res = _ingest_fused_multi(
-            self.columns, sub, jnp.asarray(out_vecs, jnp.float32),
+            old, sub, jnp.asarray(out_vecs, jnp.float32),
             jnp.int32(stream_base), jnp.int32(t0), jnp.int32(self.n_rows),
             sstates, sfvals, T=T, sspecs=sspecs)
-        if sspecs:
-            self.columns, states = res
-            self.standing.absorb(states)
-        else:
-            self.columns = res
+        self._land(old, res, sspecs)
         self.n_rows += V * T
         self.t_max = max(self.t_max, t0 + T - 1)
         store_obs_batch(self.obs, V, T)
@@ -334,12 +351,13 @@ class SegmentStore:
         assert out_vecs.ndim == 2 and out_vecs.shape[1] == self.out_dim
         keep = None if valid is None else np.asarray(valid, bool)
         n_new = V if keep is None else int(keep.sum())
-        with span("sink.ingest", t=t, rows=n_new):
+        with span("sink.ingest", t=t, rows=n_new) as sp:
             self._reserve(n_new)
             sub = {src: traces[src] for src, _ in _RUN_KEYS}
             sstates, sfvals, sspecs = _standing_args(self)
             obs = self.obs
-            args = (self.columns, sub,
+            old = self.columns
+            args = (old, sub,
                     store_put(obs, quality, jnp.float32),
                     store_put(obs, out_vecs, jnp.float32),
                     store_put(obs, t, jnp.int32),
@@ -354,11 +372,7 @@ class SegmentStore:
                     store_put(obs, np.ones(V, bool) if keep is None
                               else keep),
                     sstates, sfvals, sspecs=sspecs)
-            if sspecs:
-                self.columns, states = res
-                self.standing.absorb(states)
-            else:
-                self.columns = res
+            sp.set_metadata(donated=self._land(old, res, sspecs))
         self.n_rows += n_new
         if n_new:
             self.t_max = max(self.t_max, t)
@@ -375,18 +389,31 @@ class SegmentStore:
         self._reserve(n)
         upd = {k: jnp.asarray(v) for k, v in rows.items()}
         sstates, sfvals, sspecs = _standing_args(self)
+        old, offset = self.columns, jnp.int32(self.n_rows)
         if sspecs:
-            self.columns, states = _scatter_fold(
-                self.columns, upd, jnp.int32(self.n_rows), sstates,
-                sfvals, sspecs=sspecs)
-            self.standing.absorb(states)
+            res = _scatter_fold(old, upd, offset, sstates, sfvals,
+                                sspecs=sspecs)
         else:
-            self.columns = _scatter(self.columns, upd,
-                                    jnp.int32(self.n_rows))
+            res = _scatter(old, upd, offset)
+        self._land(old, res, sspecs)
         self.n_rows += n
         self.t_max = max(self.t_max, int(np.max(np.asarray(rows["t"]))))
         store_obs_tick(self.obs, n)
         return n
+
+    def _land(self, old, res, sspecs) -> int:
+        """Adopt a write kernel's result: the new columns, and the
+        folded standing state where queries are registered. Returns 1
+        (and counts ``donated_dispatches``) when the write consumed
+        ``old``, the columns it was given."""
+        if sspecs:
+            self.columns, states = res
+            self.standing.absorb(states)
+        else:
+            self.columns = res
+        donated = int(old["t"].is_deleted())
+        self.obs["donated_dispatches"] += donated
+        return donated
 
     # -- reading -------------------------------------------------------
     def query(self, plan, **kw):
@@ -431,6 +458,9 @@ def _store_unflatten(aux, children) -> SegmentStore:
     s = SegmentStore.__new__(SegmentStore)
     s.out_dim, s.chunk_rows = out_dim, chunk_rows
     s.n_rows, s.t_max = n_rows, t_max
+    # adopts the leaves as given: a second store unflattened from the
+    # same leaves shares their buffers, and a write to either consumes
+    # them for both (fork with ``jax.tree.map(jnp.copy, store)``)
     s.columns = dict(zip(keys, children))
     # fresh counters: mutable host state can't ride through aux (it
     # must stay hashable and stable), so telemetry isn't checkpointed;
@@ -821,7 +851,7 @@ class ShardedStore:
                                  % self.n_shards,
                                  minlength=self.n_shards)
         n_new = int(counts.sum())
-        with span("sink.ingest", t=t, rows=n_new):
+        with span("sink.ingest", t=t, rows=n_new, donated=0):
             self._reserve(counts)
             sub = {src: traces[src] for src, _ in _RUN_KEYS}
             sstates, sfvals, sspecs = _standing_args(self)

@@ -99,7 +99,10 @@ class TieredStore:
         self.cold_scales: Dict[str, jnp.ndarray] = {}
         self.cold_int: Dict[str, jnp.ndarray] = {}
         # memoized combined view; keyed on the hot columns object (every
-        # append/spill replaces that dict) + the cold row count
+        # append/spill replaces that dict) + the cold row count. A hot
+        # write consumes the arrays of the dict kept here (the store's
+        # kernels donate them), so the key is compared by identity and
+        # never read; the view itself is a fresh copy and stays valid
         self._mat_cache = None
         self.tier_obs = _tier_obs_init()
 

@@ -15,6 +15,7 @@ these compiles (an entry written for a described chip cannot be read
 back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -172,35 +173,58 @@ def test_sharded_query_compiles_for_v5e(shard_mesh, use_pallas,
     assert "all-reduce" in hlo
 
 
+def _capacity_copies(hlo, cap):
+    """HLO lines that copy a buffer with ``cap`` rows."""
+    pat = re.compile(r"= \w+\[%d[,\]].*\scopy\(" % cap)
+    return [line.strip() for line in hlo.splitlines() if pat.search(line)]
+
+
+@pytest.mark.parametrize("standing", [True, False],
+                         ids=["standing", "plain"])
 def test_masked_tick_pallas_standing_fold_compiles_for_v5e(one_chip,
-                                                           monkeypatch):
-    """The elastic pool's masked tick with 64 standing queries whose
-    folds take the Pallas delta: the live-slot mask is one more filter
-    operand of the fused kernel."""
+                                                           monkeypatch,
+                                                           standing):
+    """The elastic pool's masked tick, with 64 standing queries whose
+    folds take the Pallas delta (the live-slot mask is one more filter
+    operand of the fused kernel) and without: either way the store's
+    columns are donated, so every column aliases an output and no
+    capacity-sized buffer is copied."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from repro.warehouse.standing import _seed_state
     from repro.warehouse.store import _ingest_tick_masked
     q, cap = 64, 1 << 22
-    plan = (Filter("stream_id", "eq", 7.0),
-            WindowAgg(window=1024, value="quality", agg="mean",
-                      num_windows=9))
-    spec, fvals = Q.normalize(plan)
-    _pre, node, _ = Q.split_plan(spec)
-    fvq = tuple(_sds((q,) + np.shape(a), jnp.asarray(a).dtype, one_chip)
-                for a in fvals)
-    state = _like(_seed_state(node, (q,)), one_chip)
     traces = {"c": jnp.int32, "k": jnp.int32, "qual": jnp.float32,
               "on_s": jnp.float32, "cl_s": jnp.float32,
               "buffer_s": jnp.float32}
+    cols = _columns((cap,), one_chip)
+    kw, fold = {}, ()
+    if standing:
+        plan = (Filter("stream_id", "eq", 7.0),
+                WindowAgg(window=1024, value="quality", agg="mean",
+                          num_windows=9))
+        spec, fvals = Q.normalize(plan)
+        _pre, node, _ = Q.split_plan(spec)
+        fvq = tuple(_sds((q,) + np.shape(a), jnp.asarray(a).dtype,
+                         one_chip) for a in fvals)
+        fold = ((_like(_seed_state(node, (q,)), one_chip),), (fvq,))
+        kw = {"sspecs": ((spec, True),)}
     compiled = _ingest_tick_masked.lower(
-        _columns((cap,), one_chip),
-        {k: _sds((V,), dt, one_chip) for k, dt in traces.items()},
+        cols, {k: _sds((V,), dt, one_chip) for k, dt in traces.items()},
         _sds((V,), jnp.float32, one_chip), _sds((V, K), jnp.float32,
                                                 one_chip),
         _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
         _sds((V,), jnp.int32, one_chip), _sds((V,), jnp.bool_, one_chip),
-        (state,), (fvq,), sspecs=((spec, True),)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        *fold, **kw).compile()
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == standing
+    # the columns' bytes in the chip's layout: the arguments of a
+    # program that takes nothing else
+    col_bytes = jax.jit(lambda c: c["t"][0], keep_unused=True).lower(
+        cols).compile().memory_analysis().argument_size_in_bytes
+    assert col_bytes >= 68 * cap                 # 8 scalars + 9 floats
+    assert compiled.memory_analysis().alias_size_in_bytes == col_bytes
+    assert "input_output_alias" in hlo
+    assert _capacity_copies(hlo, cap) == []
 
 
 @pytest.mark.parametrize("name", ["window2048_sum", "wide_out_sum"])

@@ -118,8 +118,14 @@ def test_each_tick_has_one_span_per_layer_nested_with_its_t(sky, tmp_path,
         assert stats["pool.transform"]["transformed"] \
             + stats["pool.transform"]["dropped"] == V
         assert stats["sink.ingest"]["rows"] == V
+        # the single store's write consumed its columns; the sharded
+        # store does not donate
+        assert stats["sink.ingest"]["donated"] == int(not sharded)
         assert stats["sink.alert_poll"]["subscriptions"] == 1
-    assert store.telemetry().uploads > 0
+    tel = store.telemetry()
+    assert tel.uploads > 0
+    assert tel.donated_dispatches == (0 if sharded
+                                      else tel.ingest_dispatches)
 
 
 def test_span_count_per_tick_does_not_grow_with_streams(sky, tmp_path):

@@ -500,3 +500,150 @@ def test_sharded_capacity_growth_bucketed():
     assert caps2 == caps and s2.n_rows == s1.n_rows == 12 * 96
     assert sum(compile_cache_sizes().values()) == warm, \
         "sharded regrowth recompiled"
+
+
+# ---------------------------------------------------------------------------
+# ownership: every write consumes (donates) the columns it was given
+# ---------------------------------------------------------------------------
+
+def _trace_cols(rng, shape):
+    """Random switch traces with ``shape`` leaves, as host numpy."""
+    return {"c": rng.integers(0, 4, shape).astype(np.int32),
+            "k": rng.integers(0, D, shape).astype(np.int32),
+            "qual": rng.random(shape).astype(np.float32),
+            "on_s": (rng.random(shape) * 20).astype(np.float32),
+            "cl_s": (rng.random(shape) * 5).astype(np.float32),
+            "buffer_s": (rng.random(shape) * 40).astype(np.float32)}
+
+
+def _rows_from(tr, quality, out, stream_id, t):
+    """The rows a write of these (flat) traces should land."""
+    return {"stream_id": np.asarray(stream_id, np.int32),
+            "t": np.asarray(t, np.int32), "category": tr["c"],
+            "k": tr["k"], "quality": quality, "on_core_s": tr["on_s"],
+            "cloud_core_s": tr["cl_s"], "buffer_s": tr["buffer_s"],
+            "out": out}
+
+
+def _dev(tr):
+    return {k: jnp.asarray(v) for k, v in tr.items()}
+
+
+def _write(path, store, rng, i):
+    """Write ``i`` of one kind through the store's public API; returns
+    the rows it should land, computed on the host."""
+    t0 = 1000 * i
+    if path == "append_rows":
+        rows = _random_rows(40, D, seed=50 + i, t0=t0)
+        store.append_rows(rows)
+        return rows
+    if path in ("tick", "tick_masked"):
+        V = 16
+        tr = _trace_cols(rng, (V,))
+        q = rng.random(V).astype(np.float32)
+        out = rng.random((V, D)).astype(np.float32)
+        if path == "tick":
+            store.ingest_tick(_dev(tr), quality=q, out_vecs=out, t=t0)
+            return _rows_from(tr, q, out, np.arange(V), np.full(V, t0))
+        ids = rng.permutation(100)[:V]
+        keep = rng.random(V) < 0.6
+        store.ingest_tick(_dev(tr), quality=q, out_vecs=out, t=t0,
+                          stream_ids=ids, valid=keep)
+        return {k: v[keep] for k, v in _rows_from(
+            tr, q, out, ids, np.full(V, t0)).items()}
+    n_w, W, T = 3, 8, 21                       # T < n_w * W: tail padding
+    if path == "fused":
+        tr = _trace_cols(rng, (n_w, W))
+        out = rng.random((T, D)).astype(np.float32)
+        store.ingest_fused(_dev(tr), out, stream_id=5, t0=t0)
+        flat = {k: v.reshape(-1)[:T] for k, v in tr.items()}
+        return _rows_from(flat, flat["qual"], out, np.full(T, 5),
+                          t0 + np.arange(T))
+    V = 3                                      # fused_multi
+    tr = _trace_cols(rng, (n_w, V, W))
+    out = rng.random((V, T, D)).astype(np.float32)
+    store.ingest_fused_multi(_dev(tr), out, stream_base=7, t0=t0)
+    flat = {k: np.swapaxes(v, 0, 1).reshape(V, -1)[:, :T].reshape(-1)
+            for k, v in tr.items()}
+    return _rows_from(flat, flat["qual"], out.reshape(V * T, D),
+                      7 + np.repeat(np.arange(V), T),
+                      t0 + np.tile(np.arange(T), V))
+
+
+@pytest.mark.parametrize("standing", [True, False],
+                         ids=["standing", "plain"])
+@pytest.mark.parametrize("path", ["tick_masked", "tick", "fused",
+                                  "fused_multi", "append_rows"])
+def test_every_write_consumes_the_columns_it_was_given(path, standing):
+    """Each write kernel donates the store's columns: the arrays the
+    store held before a write read ``is_deleted()`` after it, every
+    write counts in ``donated_dispatches``, and the rows and standing
+    answers equal the numpy reference of the same rows."""
+    store = SegmentStore(out_dim=D, chunk_rows=64)
+    plan = (Filter("quality", "ge", 0.25),
+            GroupBy("category", "quality", agg="sum", num_groups=4))
+    reg = StandingQueries(store)
+    h = reg.register(plan) if standing else None
+    rng = np.random.default_rng(40)
+    landed, in_place = [], 0
+    for i in range(4):
+        old, cap = store.columns, store.capacity
+        landed.append(_write(path, store, rng, i))
+        # a write that grows the store donates the grown copy instead
+        if store.capacity == cap:
+            assert all(v.is_deleted() for v in old.values()), (path, i)
+            in_place += 1
+        assert not any(v.is_deleted() for v in store.columns.values())
+        tel = store.telemetry()
+        assert tel.donated_dispatches == tel.ingest_dispatches == i + 1
+    assert in_place >= 1 and store.capacity > 0
+    want = {k: np.concatenate([r[k] for r in landed]) for k in landed[0]}
+    got = store.host_rows()
+    for k in want:
+        _eq(got[k], want[k], err_msg=k)
+    if standing:
+        table, mask = reg.answer(h)
+        ref, rmask = execute_ref(want, len(want["t"]), plan)
+        _eq(mask, rmask)
+        for k in ref:
+            _eq(table[k], ref[k], err_msg=k)
+
+
+def test_tiered_spill_and_materialize_across_donating_ingests():
+    """The tiered view survives the hot tier's donated writes: a write
+    retires the hot columns the memoized view was keyed on (the next
+    ``materialize`` rebuilds it with the new rows), a view a caller
+    already holds stays alive (it is a copy, never donated), and
+    spills between writes lose no row."""
+    store = SegmentStore(out_dim=D, chunk_rows=16)
+    ts = TieredStore(store, seed=5)
+    reg = StandingQueries(ts)
+    plan = (GroupBy("category", "quality", agg="max", num_groups=4),)
+    h = reg.register(plan)
+    count = (GroupBy("category", "t", agg="count", num_groups=4),)
+    rng = np.random.default_rng(41)
+    landed = []
+    for i in range(4):
+        landed.append(_write("append_rows", store, rng, 2 * i))
+        assert ts.spill(keep_hot=0) > 0
+        view, n = ts.materialize()
+        assert ts.materialize()[0] is view        # memoized
+        hot, cap = store.columns, store.capacity
+        landed.append(_write("tick_masked", store, rng, 2 * i + 1))
+        assert store.capacity == cap
+        assert all(v.is_deleted() for v in hot.values())
+        assert not any(v.is_deleted() for v in view.values())
+        view2, n2 = ts.materialize()
+        assert view2 is not view and n2 == n + len(landed[-1]["t"])
+        want = {k: np.concatenate([r[k] for r in landed])
+                for k in landed[0]}
+        table, mask = ts.query(count)
+        ref, rmask = execute_ref(want, n2, count)
+        _eq(mask, rmask)
+        _eq(table["count"], ref["count"])          # integer: lossless
+        stand, smask = reg.answer(h)
+        ref, rmask = execute_ref(want, n2, plan)
+        _eq(smask, rmask)
+        _eq(stand["quality"], ref["quality"])      # folded at ingest
+    tel = ts.telemetry()
+    assert tel.donated_dispatches == tel.ingest_dispatches == 8

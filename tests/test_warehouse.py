@@ -3,6 +3,7 @@ plans vs the numpy reference, zero-recompile guarantees, hot/cold
 tiering, and checkpoint persistence."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.fused_ingest_bench import _synthetic_fitted
@@ -439,6 +440,25 @@ def test_store_is_a_pytree():
     total = jax.jit(lambda s: s.columns["quality"].sum())(store)
     np.testing.assert_allclose(
         float(total), float(store.columns["quality"].sum()), rtol=1e-6)
+
+
+def test_unflattened_store_shares_columns_and_a_copy_forks_them():
+    """A store unflattened from another's leaves shares their buffers,
+    so a write to either consumes them for both; a copy of the leaves
+    is a fork that keeps the old rows."""
+    store = SegmentStore(out_dim=2, chunk_rows=128)
+    first = _random_rows(100, 2, seed=3)
+    store.append_rows(first)
+    leaves, treedef = jax.tree.flatten(store)
+    shared = jax.tree.unflatten(treedef, leaves)
+    fork = jax.tree.map(jnp.copy, store)
+    store.append_rows(_random_rows(10, 2, seed=4, t0=100))
+    assert all(v.is_deleted() for v in shared.columns.values())
+    assert not any(v.is_deleted() for v in fork.columns.values())
+    got = fork.host_rows()
+    for k in first:
+        np.testing.assert_array_equal(got[k], first[k], err_msg=k)
+    assert store.n_rows == 110 and store.host_rows()["t"][-1] == 109
 
 
 def test_blocked_sums_hold_million_row_groups_to_float64():
