@@ -494,6 +494,15 @@ def standing_answer(sharded: bool):
                          {"spec": spec, "sharded": bool(sharded)})
 
 
+def standing_poll(sharded: bool):
+    from repro.warehouse.standing import _poll_kernel
+    spec, fvq, state = _standing_args("filter_groupby",
+                                      sharded=bool(sharded))
+    return EngineExample(_poll_kernel, ((state,), (fvq,)),
+                         {"specs": (spec,), "slots": ((0, _Q_STAND - 1),),
+                          "sharded": bool(sharded)})
+
+
 def store_scatter_standing():
     """``append_rows`` with a registered standing query: the scatter
     AND the incremental fold in the one jitted program."""

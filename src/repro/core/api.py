@@ -33,6 +33,7 @@ from repro.core.switcher import (SwitchTables, _masked_switch, init_state,
                                  init_state_multi, register_cache_probe,
                                  stack_tables, switch_step,
                                  switch_step_multi)
+from repro.obs.packing import pack32, unpack32
 from repro.obs.spans import install_gc_spans, span
 from repro.obs.telemetry import HostTelemetry
 
@@ -230,6 +231,12 @@ def _pool_replan_stacked(params, bufs, centers, cost, budget, use_model,
         return solve_lp_stacked(qual, cost, r, budget, weights=priority)
 
 
+# the tick's outs the host reads, packed in this order in the tick's
+# ``decisions`` out (``active`` is the slot mask, passed through)
+_DECISIONS = ("k", "c", "buffer_s", "dropped", "shed", "active", "on_s",
+              "cl_s")
+
+
 def _pool_tick_fn(state, q_meas, q_valid, quals, arr, active, priority,
                   alpha, tables, capacity_core_s, watermark_frac):
     """One elastic-pool tick, fully fused: fold last tick's measured
@@ -282,6 +289,9 @@ def _pool_tick_fn(state, q_meas, q_valid, quals, arr, active, priority,
                     buffer_s=jnp.where(shed, shed_buf, outs["buffer_s"]),
                     dropped=outs["dropped"] | shed,
                     shed=shed)
+    # everything the host reads of the tick, for one transfer
+    read = dict(outs, active=active)
+    outs["decisions"] = pack32([read[name] for name in _DECISIONS])
     return new_state, outs
 
 
@@ -702,13 +712,13 @@ class SkyscraperPool:
                 with span("pool.replan", t=t, joint=self._joint_plan):
                     self._replan()
             sp.set_metadata(uploads=tel.uploads - uploads)
-        with span("pool.pull", t=t, pulls=6):
-            ks = tel.pull(outs["k"])
-            cats = tel.pull(outs["c"])
-            bufs_s = tel.pull(outs["buffer_s"])
-            drops = tel.pull(outs["dropped"])
-            sheds = tel.pull(outs["shed"])
-            active_np = tel.pull(self._active)
+        with span("pool.pull", t=t, pulls=1):
+            avals = dict(outs, active=self._active)
+            dec = dict(zip(_DECISIONS, unpack32(
+                tel.pull(outs.pop("decisions")),
+                [avals[name] for name in _DECISIONS])))
+        ks, cats, bufs_s, drops, sheds, active_np = (
+            dec[name] for name in _DECISIONS[:6])
         statuses, results = [], []
         q_np = np.zeros(self._cap, np.float32)
         q_valid = np.zeros(self._cap, bool)
@@ -737,8 +747,8 @@ class SkyscraperPool:
             n_done = int(q_valid.sum())
             sp.set_metadata(transformed=n_done,
                             dropped=len(slots) - n_done)
-        with span("pool.recorder", t=t) as sp:
-            sp.set_metadata(pulls=tel.update(outs, valid=active_np))
+        with span("pool.recorder", t=t):
+            tel.update(dec, valid=active_np)
         # measured qualities fold into the NEXT tick's carried state
         # (inside the tick kernel — no extra dispatch)
         self._pending_q = q_np

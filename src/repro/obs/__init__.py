@@ -9,7 +9,10 @@
    puts ``uploads``) and the store's ``obs`` dict (dispatches, the
    ``donated_dispatches`` among them that wrote in place, standing
    refreshes, alerts, and its own ``host_pulls``/``uploads``). They
-   count whether or not the pool records per-stream telemetry.
+   count whether or not the pool records per-stream telemetry. A tick
+   has two sync points, each ONE read of a packed array
+   (``repro.obs.packing``): the decisions after the switch, and the
+   alert poll's rows after the ingest — two ``host_pulls`` a tick.
 3. **Spans** — ``span(name, **counts)``, a ``jax.profiler``
    annotation, marks each layer boundary of the served tick, and
    ``install_gc_spans`` names every Python collection. Under
@@ -27,16 +30,18 @@
    ``pool.dispatch``       argument uploads, ``_pool_tick``,    ``uploads``
                            ``_pool_shift``
    ``pool.replan``         the replan enqueue (in dispatch)     ``joint``
-   ``pool.pull``           the blocking reads of the decisions  ``pulls``
+   ``pool.pull``           the one blocking read of the         ``pulls`` (1)
+                           decisions, unpacked
    ``pool.transform``      per-slot statuses and ``proc_fn``    ``transformed``, ``dropped``
-   ``pool.recorder``       ``HostTelemetry.update``             ``pulls``
+   ``pool.recorder``       ``HostTelemetry.update`` (no read)   ``t``
    ``pool.load``           the sink's rows: stream ids,         ``t``
                            measured qualities, output vectors
    ``sink.ingest``         host side of a store's               ``rows``, ``donated`` (1 when the
                            ``ingest_tick``                      write consumed the old columns)
    ``sink.grow``           the store growing a capacity rung    ``capacity``
                            (in ingest)
-   ``sink.alert_poll``     ``StandingQueries.poll``             ``subscriptions``, ``pulls``, ``fired``
+   ``sink.alert_poll``     ``StandingQueries.poll``: one        ``subscriptions``, ``pulls`` (1 with
+                           dispatch, one blocking read          subscriptions), ``fired``
    ``host.gc``             one Python collection                ``generation``, ``collected``
    ======================  ===================================  =====================================
 

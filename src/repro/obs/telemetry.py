@@ -292,10 +292,11 @@ class HostTelemetry:
     the pool pulls and puts (``pull`` / ``put``).
 
     With ``record=True`` it is also a sequential float32 accumulator
-    over per-tick switch outs. Updates happen host-side from arrays the
-    pool already materializes each tick, so telemetry adds ZERO device
-    dispatches (its extra reads are transfers of already-computed
-    outputs, not new programs, and count in ``host_pulls``)."""
+    over per-tick switch outs. Updates happen host-side from the arrays
+    the pool already read in its tick's one packed transfer (``on_s``
+    and ``cl_s`` ride in it), so telemetry adds ZERO device dispatches
+    and ZERO reads: a sync point of the tick is one ``host_pulls``
+    whether or not the recorder is on."""
 
     def __init__(self, n_streams: int, k0: int, record: bool = True):
         self.V = int(n_streams)
@@ -320,26 +321,22 @@ class HostTelemetry:
         self.uploads += not isinstance(x, jax.Array)
         return jnp.asarray(x, dtype)
 
-    def update(self, outs, valid=None) -> int:
-        """One pool tick: ``outs`` is the ``switch_step_multi`` outs
-        dict ((V,) leaves, device or host). ``valid`` (V,) bool masks
-        slots that took no step this tick (the elastic pool's
-        retired/empty slots) — their counters are untouched, matching
-        the fused engines' masked-step no-op contract. Without
-        ``record`` only the tick is counted. Returns the host pulls it
-        made: ``on_s`` and ``cl_s`` (the pool has already pulled ``k``,
-        ``dropped`` and ``buffer_s``, whose host copies are cached)."""
+    def update(self, outs, valid=None) -> None:
+        """One pool tick: ``outs`` holds the tick's host numpy ``k``,
+        ``dropped``, ``buffer_s``, ``on_s`` and ``cl_s`` ((V,) each, as
+        the pool unpacked them); it makes no read of its own. ``valid``
+        (V,) bool masks slots that took no step this tick (the elastic
+        pool's retired/empty slots) — their counters are untouched,
+        matching the fused engines' masked-step no-op contract. Without
+        ``record`` only the tick is counted."""
         self.ticks += 1
         if not self.record:
-            return 0
-        pulls = self.host_pulls
+            return
         self._k_prev = _accumulate(
-            self.counters, self._k_prev, np.asarray(outs["k"]),
-            np.asarray(outs["dropped"]), np.asarray(outs["buffer_s"]),
-            self.pull(outs["on_s"]), self.pull(outs["cl_s"]),
+            self.counters, self._k_prev, outs["k"], outs["dropped"],
+            outs["buffer_s"], outs["on_s"], outs["cl_s"],
             np.ones((self.V,), bool) if valid is None
             else np.asarray(valid, bool))
-        return self.host_pulls - pulls
 
     def grow(self, n_streams: int) -> None:
         """Widen the stream axis to ``n_streams`` slots (elastic-pool

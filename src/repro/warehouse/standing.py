@@ -76,6 +76,7 @@ from repro.analysis.registry import example_builder, register_engine
 from repro.core.switcher import register_cache_probe
 from repro.kernels.warehouse_agg import CMP as _CMP
 from repro.kernels.warehouse_agg import FusedAggSpec, fused_segment_agg
+from repro.obs.packing import pack32, unpack32
 from repro.obs.spans import span
 from repro.warehouse.query import (Filter, GroupBy, MultiGroupBy, TopK,
                                    WindowAgg, _apply_nodes, _FilterRef,
@@ -255,6 +256,34 @@ def _answer_kernel(state, fvals, *, spec, sharded):
             state, fvals)
 
 
+def _poll_rows(states, fvals, *, specs, slots, sharded):
+    """Per subscribed group (``specs``, stacked ``states`` /
+    ``fvals``), its answer tables and mask gathered at the group's
+    subscribed query ``slots``: a ``(len(slots), ...)`` table and mask
+    for each group, in order (traced)."""
+    out = []
+    for st, fv, spec, sl in zip(states, fvals, specs, slots):
+        table, mask = _answer_kernel(st, fv, spec=spec, sharded=sharded)
+        idx = jnp.asarray(sl, jnp.int32)
+
+        def take(v):
+            return v.at[idx].get(mode="promise_in_bounds")
+
+        out.append(({k: take(v) for k, v in table.items()}, take(mask)))
+    return tuple(out)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("specs", "slots", "sharded"))
+def _poll_kernel(states, fvals, *, specs, slots, sharded):
+    """The alert poll's one dispatch: every subscribed group's answer
+    (``_answer_kernel``), gathered at its subscribed slots
+    (``_poll_rows``) and packed bit for bit into one int32 vector, so
+    the host reads the whole poll in one transfer."""
+    return pack32(jax.tree.leaves(_poll_rows(
+        states, fvals, specs=specs, slots=slots, sharded=sharded)))
+
+
 # ---------------------------------------------------------------------------
 # registry
 # ---------------------------------------------------------------------------
@@ -404,6 +433,7 @@ class StandingQueries:
         self._subs: Dict[int, _Sub] = {}
         self._active: List[_Group] = []
         self._next = 0
+        self._poll_plan = None        # poll()'s layout; register resets it
 
     def __len__(self) -> int:
         return len(self._queries)
@@ -467,6 +497,7 @@ class StandingQueries:
         g.add(q)
         self._queries[handle] = q
         self.host.obs["standing_queries"] = len(self._queries)
+        self._poll_plan = None
         return handle
 
     def subscribe(self, plan, predicate: Filter, *,
@@ -537,28 +568,65 @@ class StandingQueries:
         return to_host(table, mask)
 
     # -- alerts --------------------------------------------------------
+    def _plan_poll(self):
+        """The poll's static layout, rebuilt after a ``register``: the
+        subscribed groups' specs and slots (the ``_poll_kernel``
+        statics), each subscription's (group, row) in its output, and
+        the output's leaf shapes and tree for ``unpack32``."""
+        slots: Dict[tuple, List[int]] = {}
+        where = []
+        for sub in self._subs.values():
+            q = self._queries[sub.handle]
+            group = slots.setdefault(q.spec, [])
+            where.append((list(slots).index(q.spec), len(group)))
+            group.append(q.slot)
+        kw = dict(specs=tuple(slots),
+                  slots=tuple(tuple(v) for v in slots.values()),
+                  sharded=self.sharded)
+        shapes = jax.eval_shape(functools.partial(_poll_rows, **kw),
+                                *self._poll_operands(kw["specs"]))
+        avals, tree = jax.tree.flatten(shapes)
+        return kw, where, avals, tree
+
+    def _poll_operands(self, specs):
+        """(states, fvals) of the groups of ``specs``: ``_poll_kernel``'s
+        operands."""
+        groups = [self._groups[spec] for spec in specs]
+        return (tuple(g.state for g in groups),
+                tuple(g.fvals_dev for g in groups))
+
+    def _subscribed_rows(self):
+        """Each subscription's answer row and row mask (host numpy), in
+        subscription order: one ``_poll_kernel`` dispatch and one
+        blocking read for all of them."""
+        if not self._subs:
+            return []
+        if self._poll_plan is None:
+            self._poll_plan = self._plan_poll()
+        kw, where, avals, tree = self._poll_plan
+        packed = _poll_kernel(*self._poll_operands(kw["specs"]), **kw)
+        self.host.obs["host_pulls"] += 1
+        rows = jax.tree.unflatten(tree,
+                                  unpack32(np.asarray(packed), avals))
+        return [({k: v[r] for k, v in rows[gi][0].items()}, rows[gi][1][r])
+                for gi, r in where]
+
     def poll(self) -> List[Alert]:
         """Evaluate every subscription against its plan's CURRENT
-        standing answer: one answer dispatch per plan shape, then the
-        predicates host-side over the fixed-shape tables. Updates the
+        standing answer. The poll is one sync point and one read: ONE
+        dispatch answers every subscribed plan shape and gathers the
+        subscribed rows, packed, and ONE blocking transfer brings them
+        to the host, where the predicates run in numpy. Updates the
         flight-recorder counters (``alerts_checked``/``alerts_fired``,
-        and ``host_pulls`` for the answer columns read), inside a
-        ``sink.alert_poll`` span."""
+        and ``host_pulls`` for the read), inside a ``sink.alert_poll``
+        span."""
         alerts: List[Alert] = []
-        cache: Dict[int, tuple] = {}
         obs = self.host.obs
         pulls, fired_n = obs["host_pulls"], obs["alerts_fired"]
         with span("sink.alert_poll",
                   subscriptions=len(self._subs)) as sp:
-            for sub in self._subs.values():
-                q = self._queries[sub.handle]
-                g = self._group_of(q)
-                if id(g) not in cache:
-                    cache[id(g)] = self.group_answers(g)
-                table, mask = cache[id(g)]
-                row = {k: np.asarray(v[q.slot]) for k, v in table.items()}
-                valid = np.asarray(mask[q.slot])
-                obs["host_pulls"] += len(row) + 1
+            for sub, (row, valid) in zip(self._subs.values(),
+                                         self._subscribed_rows()):
                 col = row[sub.predicate.column]
                 dt = np.float64 if np.issubdtype(col.dtype, np.integer) \
                     else np.float32
@@ -579,6 +647,7 @@ class StandingQueries:
 register_cache_probe(
     "warehouse_standing",
     lambda: (_backfill._cache_size() + _answer_kernel._cache_size()
+             + _poll_kernel._cache_size()
              + sum(k._cache_size() for k in _SHARDED_FOLD.values())))
 
 register_engine("standing_backfill",
@@ -606,4 +675,13 @@ register_engine("standing_answer",
 register_engine("standing_answer_sharded",
                 example_builder("standing_answer", True),
                 probe=lambda: _answer_kernel._cache_size(),
+                probe_name="warehouse_standing")
+register_engine("standing_poll",
+                example_builder("standing_poll", False),
+                probe=lambda: _poll_kernel._cache_size(),
+                covers=("repro.warehouse.standing:_poll_kernel",),
+                probe_name="warehouse_standing")
+register_engine("standing_poll_sharded",
+                example_builder("standing_poll", True),
+                probe=lambda: _poll_kernel._cache_size(),
                 probe_name="warehouse_standing")

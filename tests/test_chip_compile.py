@@ -227,6 +227,31 @@ def test_masked_tick_pallas_standing_fold_compiles_for_v5e(one_chip,
     assert _capacity_copies(hlo, cap) == []
 
 
+def test_alert_poll_compiles_for_v5e(one_chip):
+    """The alert poll's one program: a buffer high-water alert over 8192
+    stream ids and a subscribed slot of a 64-query window group, both
+    answered, gathered and packed into one int32 vector."""
+    from repro.warehouse.standing import _poll_kernel, _seed_state
+    plans = ((GroupBy("stream_id", "buffer_s", "max", num_groups=8192),),
+             (Filter("stream_id", "eq", 7.0),
+              WindowAgg(window=1024, value="quality", agg="mean",
+                        num_windows=9)))
+    states, fvals, specs = [], [], []
+    for plan, q in zip(plans, (1, 64)):
+        spec, fv = Q.normalize(plan)
+        _pre, node, _ = Q.split_plan(spec)
+        states.append(_like(_seed_state(node, (q,)), one_chip))
+        fvals.append(tuple(_sds((q,) + np.shape(a), jnp.asarray(a).dtype,
+                                one_chip) for a in fv))
+        specs.append(spec)
+    compiled = _poll_kernel.lower(
+        tuple(states), tuple(fvals), specs=tuple(specs),
+        slots=((0,), (5,)), sharded=False).compile()
+    out, = jax.tree.leaves(compiled.out_info)
+    # per group: its key column, value, count and mask, one row each
+    assert out.dtype == jnp.int32 and out.shape == (4 * 8192 + 4 * 9,)
+
+
 @pytest.mark.parametrize("name", ["window2048_sum", "wide_out_sum"])
 def test_blocked_sum_query_compiles_for_v5e(name, one_chip):
     """The XLA path's two-level float sums over a 2^26-row store (the

@@ -114,7 +114,10 @@ def test_each_tick_has_one_span_per_layer_nested_with_its_t(sky, tmp_path,
                           and x[:3] != (name, s, e)]
                 assert max(around, key=lambda x: x[1])[0] == PARENT[name]
         stats = {sp[0]: sp[3] for sp in inside}
-        assert stats["pool.pull"]["pulls"] == 6
+        # one blocking read at each sync point: the decisions, the poll
+        assert stats["pool.pull"]["pulls"] == 1
+        assert stats["sink.alert_poll"]["pulls"] == 1
+        assert stats["pool.tick"]["host_pulls"] == 2
         assert stats["pool.transform"]["transformed"] \
             + stats["pool.transform"]["dropped"] == V
         assert stats["sink.ingest"]["rows"] == V
@@ -159,16 +162,15 @@ def test_transfer_counts_do_not_need_the_flight_recorder(sky):
                            tel.host_pulls, store.obs["uploads"],
                            store.obs["host_pulls"])
     off, on = seen[False], seen[True]
-    # the recorder's own reads (on_s and cl_s, each tick) are the only
-    # transfers it adds
-    assert on[:3] == off[:3] and on[4:] == off[4:]
-    assert on[3] == off[3] + 2 * n
+    # the recorder reads nothing of its own: on_s and cl_s ride in the
+    # tick's one packed read
+    assert on == off
     ticks, replans, uploads, pulls, s_up, s_pulls = off
     assert (ticks, replans) == (n, 1)
-    # per tick: six decision reads; per replan three operands; per
-    # tick five dispatch operands and the measured qualities
-    assert pulls == 6 * n and uploads == 6 * n + 3 * replans
+    # per tick: one read of the decisions; per replan three operands;
+    # per tick five dispatch operands and the measured qualities
+    assert pulls == n and uploads == 6 * n + 3 * replans
     # per tick the store uploads t, row offset, stream ids, the mask;
-    # the poll reads the alert's answer row (three columns) and mask
-    assert s_up == 4 * n and s_pulls == 4 * n
+    # the poll reads the alert's answer row and mask in one read
+    assert s_up == 4 * n and s_pulls == n
     assert pool.telemetry().extras["host_pulls"] == on[3]
